@@ -32,9 +32,10 @@ def main():
     from repro.core.twotower import TwoTowerConfig, init_params, query_tower
     from repro.data.synthetic import make_database, make_queries_in_dist
     from repro.graphs.knn import exact_knn, knn_graph, recall_at_k
+    from repro.launch.mesh import make_host_mesh
 
     shape = (args.devices // 2, 2)
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_host_mesh(shape, ("data", "model"))
     print(f"mesh: {dict(mesh.shape)} over {mesh.size} devices")
 
     db, _ = make_database("sift10m-like", args.n, seed=0)

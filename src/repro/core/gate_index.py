@@ -274,9 +274,10 @@ class GateIndex:
         warm.  Cosine always gets the precomputed ``1/‖row‖`` cache
         (ISSUE 10 satellite: never renormalize rows per hop); ``fused_q8``
         gets the device codebook, quantizing on first use; real-TPU
-        ``fused`` with ``d % 128 != 0`` gets the cached lane-aligned db
-        copy — padding inside the jitted search would re-materialize an
-        O(N·d) copy per batch."""
+        ``fused`` with ``d != 128`` gets the cached lane-row view the kernel
+        DMAs from (``repro.kernels.gather_dist.lane_rows``) — building it
+        inside the jitted search would re-materialize an O(N·d) copy per
+        batch."""
         dev = self._device()
         kw: Dict = {}
         if params.metric == "cosine":
@@ -296,15 +297,13 @@ class GateIndex:
                 )
             kw["quant"] = dev["quant"]
         if (params.kernel == "fused" and not params.kernel_interpret
-                and dev["db"].shape[1] % 128):
+                and dev["db"].shape[1] != 128):
+            from repro.kernels.gather_dist import lane_rows
             from repro.kernels.ops import _on_tpu
 
             if _on_tpu():
                 if "db_lane" not in dev:
-                    pad = (-dev["db"].shape[1]) % 128
-                    dev["db_lane"] = jnp.pad(
-                        dev["db"], ((0, 0), (0, pad))
-                    )
+                    dev["db_lane"] = lane_rows(dev["db"])
                 kw["db_lane"] = dev["db_lane"]
         return kw
 

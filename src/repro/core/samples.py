@@ -13,9 +13,12 @@ and a NEGATIVE if                      H(q,V_i) ≥ min_q' H(q',V_i) + t_neg.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.graphs.knn import exact_knn
@@ -90,6 +93,26 @@ def top1_targets(db: np.ndarray, queries: np.ndarray) -> np.ndarray:
     return ids[:, 0].astype(np.int64)
 
 
+@functools.partial(jax.jit, static_argnames=("beam_width", "max_hops"))
+def _hops_chunk(db, neighbors, queries, hub_ids, targets, *,
+                beam_width, max_hops):
+    """(Q, n_c) hops of Algorithm 1 from every hub for a chunk of queries.
+    ``db`` and the graph are operands: closed over, they would be baked
+    into the executable as constants (2.2 GB at 1M x 128)."""
+    from repro.graphs.search import beam_search_single
+
+    def one(q, entry, target):
+        ids, d, hops, _ = beam_search_single(
+            db, neighbors, q, entry[None],
+            beam_width=beam_width, max_hops=max_hops,
+        )
+        return jnp.where(jnp.any(ids == target), hops, max_hops)
+
+    return jax.vmap(jax.vmap(one, (None, 0, None)), (0, None, 0))(
+        queries, hub_ids, targets
+    )
+
+
 def greedy_hops(
     db,
     neighbors,
@@ -102,22 +125,7 @@ def greedy_hops(
 ) -> np.ndarray:
     """Paper-implementation variant: hops of Algorithm 1 from each hub until
     the target enters the beam. (Q, n_c); batched over query-hub pairs."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.graphs.search import beam_search_single
-
     dbj, nbj = jnp.asarray(db), jnp.asarray(neighbors)
-
-    def one(q, entry, target):
-        ids, d, hops, _ = beam_search_single(
-            dbj, nbj, q, entry[None],
-            beam_width=beam_width, max_hops=max_hops,
-        )
-        found = jnp.any(ids == target)
-        return jnp.where(found, hops, max_hops)
-
-    fn = jax.jit(jax.vmap(jax.vmap(one, (None, 0, None)), (0, None, 0)))
     out = np.zeros((len(queries), len(hub_ids)), np.int32)
     qj = jnp.asarray(queries)
     hj = jnp.asarray(hub_ids, jnp.int32)
@@ -125,7 +133,10 @@ def greedy_hops(
     chunk = 64
     for s in range(0, len(queries), chunk):
         e = min(s + chunk, len(queries))
-        out[s:e] = np.asarray(fn(qj[s:e], hj, tj[s:e]))
+        out[s:e] = np.asarray(_hops_chunk(
+            dbj, nbj, qj[s:e], hj, tj[s:e],
+            beam_width=beam_width, max_hops=max_hops,
+        ))
     return out
 
 

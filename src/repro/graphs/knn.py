@@ -6,6 +6,7 @@ index construction (offline) and as ground truth in tests/benchmarks.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -15,12 +16,22 @@ import numpy as np
 
 @jax.jit
 def pairwise_sq_l2(q: jax.Array, c: jax.Array) -> jax.Array:
-    """(Q,d) x (C,d) -> (Q,C) squared L2, fp32 accumulation."""
+    """(Q,d) x (C,d) -> (Q,C) squared L2, fp32 accumulation.  HIGHEST
+    precision: the TPU's default fp32 matmul runs in bf16 passes, which
+    would make the exact kNN (the recall reference) approximate there."""
     qf = q.astype(jnp.float32)
     cf = c.astype(jnp.float32)
     qn = jnp.sum(qf * qf, axis=1, keepdims=True)
     cn = jnp.sum(cf * cf, axis=1, keepdims=True)
-    return jnp.maximum(qn - 2.0 * (qf @ cf.T) + cn.T, 0.0)
+    qc = jnp.matmul(qf, cf.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.maximum(qn - 2.0 * qc + cn.T, 0.0)
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _topk_chunk(qc, dbv, *, k):
+    d = pairwise_sq_l2(qc, dbv)
+    neg_d, idx = jax.lax.top_k(-d, k)
+    return idx, -neg_d
 
 
 def exact_knn(
@@ -35,17 +46,11 @@ def exact_knn(
     n = queries.shape[0]
     ids_out = np.empty((n, k), np.int32)
     d_out = np.empty((n, k), np.float32)
-
-    @jax.jit
-    def topk_chunk(qc, dbv):
-        d = pairwise_sq_l2(qc, dbv)
-        neg_d, idx = jax.lax.top_k(-d, k + (1 if exclude_self else 0))
-        return idx, -neg_d
-
+    kk = k + (1 if exclude_self else 0)
     dbj = jnp.asarray(db)
     for s in range(0, n, q_chunk):
         e = min(s + q_chunk, n)
-        idx, dist = topk_chunk(jnp.asarray(queries[s:e]), dbj)
+        idx, dist = _topk_chunk(jnp.asarray(queries[s:e]), dbj, k=kk)
         idx, dist = np.asarray(idx), np.asarray(dist)
         if exclude_self:
             # drop the self-match (distance ~0 at own index)

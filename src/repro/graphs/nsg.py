@@ -24,6 +24,7 @@ import numpy as np
 from repro.graphs.knn import exact_knn, knn_graph, medoid, pairwise_sq_l2
 from repro.graphs.params import SearchParams
 from repro.graphs.search import batched_search
+from repro.obs.trace import span
 
 
 @dataclass
@@ -54,9 +55,14 @@ def _mrng_prune_batch(node_vecs, cand_ids, cand_vecs, R):
     cv = cand_vecs.astype(jnp.float32)
     d_node = jnp.sum((cv - nv[:, None, :]) ** 2, axis=-1)  # (B, P)
     d_node = jnp.where(cand_ids < 0, jnp.inf, d_node)
-    # pairwise candidate distances (B, P, P)
+    # pairwise candidate distances (B, P, P).  HIGHEST precision: at the
+    # TPU's default (bf16 passes) the expansion's error is a large share of
+    # a neighbour distance, and the rule below compares it with the exact
+    # ``d_node``
     sq = jnp.sum(cv * cv, axis=-1)
-    d_pair = sq[:, :, None] - 2 * jnp.einsum("bpd,bqd->bpq", cv, cv) + sq[:, None, :]
+    cc = jnp.einsum("bpd,bqd->bpq", cv, cv,
+                    precision=jax.lax.Precision.HIGHEST)
+    d_pair = sq[:, :, None] - 2 * cc + sq[:, None, :]
 
     def body(i, state):
         suppressed, selected, n_sel = state
@@ -122,8 +128,9 @@ def build_nsg(
     aug_random: int = 4,
 ) -> NSG:
     n, d = db.shape
-    knn = knn_graph(db, knn_k)
-    enter = medoid(db)
+    with span("nsg.knn", n=n, k=knn_k):
+        knn = knn_graph(db, knn_k)
+        enter = medoid(db)
     dbj = jnp.asarray(db)
     # candidate-generation substrate: KNN rows + a few random long edges per
     # node (efanna-style).  Clustered data yields a cluster-disconnected KNN
@@ -135,40 +142,44 @@ def build_nsg(
     )
     knnj = jnp.asarray(sub)
 
-    prune = jax.jit(_mrng_prune_batch, static_argnums=(3,))
-    out = np.full((n, R), -1, np.int32)
-    entry = jnp.full((batch, 1), enter, jnp.int32)
-    for s in range(0, n, batch):
-        e = min(s + batch, n)
-        qs = dbj[s:e]
-        ent = entry[: e - s]
-        res = batched_search(
-            dbj, knnj, qs, ent,
-            SearchParams(k=search_l, beam_width=search_l, max_hops=search_l),
-        )
-        # pool = search results ∪ own KNN row (dedup; self removed)
-        pool = np.concatenate(
-            [np.asarray(res.ids), knn[s:e]], axis=1
-        )[:, :pool_size + 8]
-        node_idx = np.arange(s, e)[:, None]
-        pool = np.where(pool == node_idx, -1, pool)
-        # dedup within row (keep first occurrence)
-        pool_sorted = np.sort(pool, axis=1)
-        dup = np.zeros_like(pool, bool)
-        srt_idx = np.argsort(pool, axis=1, kind="stable")
-        dup_sorted = np.concatenate(
-            [np.zeros((pool.shape[0], 1), bool),
-             pool_sorted[:, 1:] == pool_sorted[:, :-1]], axis=1
-        )
-        np.put_along_axis(dup, srt_idx, dup_sorted, axis=1)
-        pool = np.where(dup, -1, pool)[:, :pool_size]
-        cand_ids = jnp.asarray(pool)
-        cand_vecs = dbj[jnp.maximum(cand_ids, 0)]
-        sel = prune(dbj[s:e], cand_ids, cand_vecs, R)
-        out[s:e] = np.asarray(sel)
+    with span("nsg.search_prune", n=n, search_l=search_l):
+        prune = jax.jit(_mrng_prune_batch, static_argnums=(3,))
+        out = np.full((n, R), -1, np.int32)
+        entry = jnp.full((batch, 1), enter, jnp.int32)
+        for s in range(0, n, batch):
+            e = min(s + batch, n)
+            qs = dbj[s:e]
+            ent = entry[: e - s]
+            res = batched_search(
+                dbj, knnj, qs, ent,
+                SearchParams(k=search_l, beam_width=search_l,
+                             max_hops=search_l),
+            )
+            # pool = search results ∪ own KNN row (dedup; self removed)
+            pool = np.concatenate(
+                [np.asarray(res.ids), knn[s:e]], axis=1
+            )[:, :pool_size + 8]
+            node_idx = np.arange(s, e)[:, None]
+            pool = np.where(pool == node_idx, -1, pool)
+            # dedup within row (keep first occurrence)
+            pool_sorted = np.sort(pool, axis=1)
+            dup = np.zeros_like(pool, bool)
+            srt_idx = np.argsort(pool, axis=1, kind="stable")
+            dup_sorted = np.concatenate(
+                [np.zeros((pool.shape[0], 1), bool),
+                 pool_sorted[:, 1:] == pool_sorted[:, :-1]], axis=1
+            )
+            np.put_along_axis(dup, srt_idx, dup_sorted, axis=1)
+            pool = np.where(dup, -1, pool)[:, :pool_size]
+            cand_ids = jnp.asarray(pool)
+            cand_vecs = dbj[jnp.maximum(cand_ids, 0)]
+            sel = prune(dbj[s:e], cand_ids, cand_vecs, R)
+            out[s:e] = np.asarray(sel)
 
-    out = _add_reverse_edges(out, R)
-    out = _repair_connectivity(db, out, enter)
+    with span("nsg.reverse_edges", n=n):
+        out = _add_reverse_edges(out, R)
+    with span("nsg.repair", n=n):
+        out = _repair_connectivity(db, out, enter)
     return NSG(neighbors=out, enter_id=enter, R=out.shape[1])
 
 
@@ -189,21 +200,28 @@ def _add_reverse_edges(neighbors: np.ndarray, R: int) -> np.ndarray:
     return neighbors
 
 
-def _repair_connectivity(db, neighbors, enter) -> np.ndarray:
-    """BFS from medoid; attach every unreachable node to its nearest
-    reachable node (NSG tree_grow).  Rows may overflow the degree cap — the
-    adjacency is re-padded to the new max degree (matches the reference NSG
-    implementation, which lets repair edges exceed R)."""
-    n, R = neighbors.shape
-    seen = np.zeros(n, bool)
-    stack = [enter]
-    seen[enter] = True
+def _mark_reachable(neighbors: np.ndarray, seen: np.ndarray, start) -> None:
+    """Mark everything reachable from ``start`` (DFS over unseen nodes)."""
+    stack = [int(start)]
+    seen[start] = True
     while stack:
         u = stack.pop()
         for v in neighbors[u]:
             if v >= 0 and not seen[v]:
                 seen[v] = True
                 stack.append(int(v))
+
+
+def _repair_connectivity(db, neighbors, enter) -> np.ndarray:
+    """DFS from the medoid; attach unreachable nodes to their nearest
+    reachable node and mark everything the attached node reaches (NSG
+    tree_grow), in waves: one exact nearest-reachable search per wave.
+    Rows may overflow the degree cap — the adjacency is re-padded to the
+    new max degree (matches the reference NSG implementation, which lets
+    repair edges exceed R)."""
+    n, R = neighbors.shape
+    seen = np.zeros(n, bool)
+    _mark_reachable(neighbors, seen, enter)
     if seen.all():
         return neighbors
     rows = [list(r[r >= 0]) for r in neighbors]
@@ -218,12 +236,14 @@ def _repair_connectivity(db, neighbors, enter) -> np.ndarray:
         attached = 0
         for j in order:
             m = int(missing[j])
+            if seen[m]:
+                continue  # reached through a node attached this wave
             r = int(reach_ids[ids[j, 0]])
             if extra[r] >= cap:
                 continue  # anchor full — m waits for the next wave
             rows[r].append(m)
             extra[r] += 1
-            seen[m] = True
+            _mark_reachable(neighbors, seen, m)
             attached += 1
         if attached == 0:  # all nearest anchors saturated: relax the cap
             cap *= 2

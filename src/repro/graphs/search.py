@@ -95,20 +95,22 @@ def _make_dist_fns(
     inside the hop loop (ISSUE 10 satellite: no per-hop padding or
     renormalization).
 
-    Kernel dispatch: the Pallas in-kernel-gather bodies run on real TPU or
-    under ``kernel_interpret=True`` (the CPU test path); otherwise ``fused``
-    falls back to the *matched* XLA formulation — same reduction shapes, so
-    fp32 results are bit-identical either way — and ``fused_q8`` to an XLA
-    dequantize-and-score of the same codes.
+    Kernel dispatch: ``fused`` runs the Pallas in-kernel-gather body on TPU,
+    or in interpret mode under ``kernel_interpret=True`` (the CPU test path,
+    refused on TPU); elsewhere it is the *matched* XLA formulation — same
+    reduction shapes, so fp32 results are bit-identical either way.
+    ``fused_q8`` scores its int8 codes with XLA on every platform (no int8
+    row DMA pays off on TPU; see ``repro.kernels.gather_dist``).
     """
-    from repro.kernels.gather_dist import gather_rows_dist, gather_rows_dist_q8
+    from repro.kernels.gather_dist import gather_rows_dist, lane_rows
     from repro.kernels.ops import _on_tpu
 
     qf = q.astype(jnp.float32)
     D = db.shape[1]
-    use_pallas = kernel in ("fused", "fused_q8") and (
-        kernel_interpret or _on_tpu()
-    )
+    on_tpu = _on_tpu()
+    if kernel_interpret and on_tpu:
+        raise ValueError("kernel_interpret is a CPU test mode; on TPU the "
+                         "compiled kernel runs")
 
     if metric == "cosine":
         qx = qf / jnp.maximum(jnp.linalg.norm(qf), 1e-9)
@@ -126,6 +128,7 @@ def _make_dist_fns(
             return jnp.where(ids < 0, INF, d)
     elif metric == "l2":
         qx = qf
+        inv = None
 
         def exact_dist(ids):
             vecs = db[jnp.maximum(ids, 0)].astype(jnp.float32)
@@ -138,35 +141,28 @@ def _make_dist_fns(
     if metric == "cosine":
         vec_bytes += 4  # the inv-norm read per scored row
 
-    if kernel == "xla" or (kernel == "fused" and not use_pallas):
+    if kernel == "xla" or (kernel == "fused" and not (kernel_interpret
+                                                      or on_tpu)):
         return exact_dist, exact_dist, vec_bytes
 
     if kernel == "fused":
-        # lane-align d for real-TPU lowering; interpret mode (CPU tests)
-        # runs unpadded so reduction shapes — and therefore bits — match
-        # the XLA reference exactly, odd d included.  The (N, d) pad must
-        # come in precomputed (``db_lane``, cached per index by
-        # GateIndex._search_kwargs) — padding here would trace an O(N·d)
-        # HBM copy into every search batch's program.  The inline fallback
-        # exists only for direct beam_search_single callers and pays that
-        # copy per batch.
+        # interpret mode reads the plain (N, d) rows, so reduction shapes —
+        # and therefore bits — match the XLA reference, odd d included.  On
+        # TPU the kernel reads the lane-row view; it must come in
+        # precomputed (``db_lane``, cached per index by
+        # GateIndex._search_kwargs) unless d = 128, where the view is ``db``
+        # itself — building it here would trace an O(N·d) HBM copy into
+        # every search batch's program.  The inline fallback exists only for
+        # direct beam_search_single callers and pays that copy per batch.
         db_k, q_k = db, qx
-        if not kernel_interpret and D % 128:
-            pad = (-D) % 128
-            db_k = db_lane if db_lane is not None else jnp.pad(
-                db, ((0, 0), (0, pad))
+        if not kernel_interpret:
+            db_k = db_lane if db_lane is not None else lane_rows(db)
+            q_k = jnp.pad(qx, ((0, (-D) % 128),))
+
+        def dist_to(ids):
+            return gather_rows_dist(
+                ids, db_k, q_k, inv, interpret=kernel_interpret
             )
-            q_k = jnp.pad(qx, ((0, pad),))
-        if metric == "cosine":
-            def dist_to(ids):
-                return gather_rows_dist(
-                    ids, db_k, q_k, inv, interpret=kernel_interpret
-                )
-        else:
-            def dist_to(ids):
-                return gather_rows_dist(
-                    ids, db_k, q_k, interpret=kernel_interpret
-                )
         return dist_to, exact_dist, vec_bytes
 
     # ---- fused_q8: approximate distances from the int8 codebook ----------
@@ -180,20 +176,6 @@ def _make_dist_fns(
     nb = scale.shape[1]
     qp = jnp.zeros((Dp,), jnp.float32).at[:D].set(qx)  # widened once
     vec_bytes = Dp + 8 * nb + (4 if metric == "cosine" else 0)
-
-    if use_pallas:
-        if metric == "cosine":
-            def dist_to(ids):
-                return gather_rows_dist_q8(
-                    ids, codes, scale, zero, qp, q_inv,
-                    interpret=kernel_interpret,
-                )
-        else:
-            def dist_to(ids):
-                return gather_rows_dist_q8(
-                    ids, codes, scale, zero, qp, interpret=kernel_interpret
-                )
-        return dist_to, exact_dist, vec_bytes
 
     def dequant_rows(ids):
         safe = jnp.maximum(ids, 0)
@@ -246,9 +228,9 @@ def beam_search_single(
     beam slots so returned distances/order are exact over that prefix (the
     beam then truncates to ``rerank`` entries).  ``inv_norms`` is the
     precomputed cosine ``1/‖row‖`` cache; omitted, it is computed once per
-    call (still never per hop).  ``db_lane`` is the precomputed lane-aligned
-    (d padded to a 128 multiple) copy of ``db`` the real-TPU ``fused``
-    kernel reads; omitted with ``d % 128 != 0``, it is padded inline —
+    call (still never per hop).  ``db_lane`` is the precomputed lane-row
+    view of ``db`` (``repro.kernels.gather_dist.lane_rows``) the TPU
+    ``fused`` kernel reads; omitted with ``d != 128``, it is built inline —
     an O(N·d) copy per batch, so serving callers should pass it
     (``GateIndex`` caches one per index).
 
@@ -394,7 +376,7 @@ def _batched_search(
     entry_ids: jax.Array,  # (B, E)
     inv_norms: Optional[jax.Array] = None,  # (N,) cosine 1/‖row‖ cache
     quant=None,                             # repro.quant.QuantizedDb pytree
-    db_lane: Optional[jax.Array] = None,    # (N, d128) lane-aligned db copy
+    db_lane: Optional[jax.Array] = None,    # (N·k, 128) lane-row view of db
     *,
     params: SearchParams,
 ):
@@ -463,9 +445,9 @@ def batched_search(
     ``params.kernel`` selects the distance path (docs/kernels.md); for
     ``"fused_q8"`` pass ``quant=`` (``repro.quant.quantize_db(db)``), for
     ``metric="cosine"`` optionally ``inv_norms=`` to reuse a precomputed
-    ``1/‖row‖`` cache across calls, and for ``"fused"`` on real TPU with
-    ``d % 128 != 0`` optionally ``db_lane=`` (the lane-aligned db copy) so
-    the padding isn't re-materialized inside every search batch.
+    ``1/‖row‖`` cache across calls, and for ``"fused"`` on TPU with
+    ``d != 128`` optionally ``db_lane=`` (``lane_rows(db)``) so the view
+    isn't re-materialized inside every search batch.
 
     ``params.instrument=False`` (default): returns ``SearchResult`` — the
     HLO is identical to the pre-telemetry program.  ``instrument=True``:

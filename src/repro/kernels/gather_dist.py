@@ -9,24 +9,16 @@ itself, which round-trips the (B, R, d) block through HBM) is untouched, and
 the block must be re-padded to lane multiples inside jit on every hop.  Kept
 as the pre-ISSUE-10 baseline and for one-shot (non-loop) distance batches.
 
-**In-kernel gather (``gather_rows_dist`` / ``gather_rows_dist_q8``)** — the
-neighbor ids arrive as a *scalar-prefetch* argument
-(``pltpu.PrefetchScalarGridSpec``, ``num_scalar_prefetch=1``): they are in
-SMEM before the kernel body runs, so the BlockSpec index map
-``lambda j, ids: (max(ids[j], 0), 0)`` steers the pipelining machinery to DMA
-exactly the R needed db rows HBM→VMEM, one (1, d) block per grid step.  The
-gathered block never exists in HBM; per hop the traffic is R row-reads plus
-R output floats.  ``gather_rows_dist_q8`` reads int8 rows of a
-``repro.quant.QuantizedDb`` codebook instead (≈4× fewer bytes per hop) and
-dequantizes in-register.  Masking (id < 0 → +inf) happens in-kernel; invalid
-slots still DMA row 0 (``max(ids[j], 0)``) but their distance is discarded.
-
-No per-hop padding: the q8 codebook is block-padded at build time and the
-fp32 path requires lane-aligned ``d`` only for real-TPU lowering — interpret
-mode (the CPU test path) runs unpadded, which keeps the kernels bit-identical
-to the matched XLA formulation in ``graphs/search.py`` even for odd ``d``
-(reduction-tree shape is preserved: per-row ``jnp.sum(axis=-1)`` over the
-same ``d``).  See docs/kernels.md for the traffic model.
+**In-kernel gather (``gather_rows_dist``)** — the neighbor ids arrive as a
+*scalar-prefetch* argument (``pltpu.PrefetchScalarGridSpec``,
+``num_scalar_prefetch=1``), so they are in SMEM before the body runs, and the
+body DMAs exactly the R needed db rows HBM→VMEM itself.  The gathered block
+never exists in HBM; per hop the traffic is R row-reads plus R output
+floats.  Invalid slots (id < 0) fetch row 0 and are masked to +inf.  On TPU
+the rows come from the lane-row view ``lane_rows(db)`` (the layout Mosaic
+can DMA one row from); interpret mode (the CPU test path) reads the plain
+(N, d) array and stays bit-identical to the XLA formulation in
+``graphs/search.py``.  See docs/kernels.md for the traffic model.
 """
 from __future__ import annotations
 
@@ -89,171 +81,121 @@ def gather_dist(
 
 
 # ---------------------------------------------------------------------------
-# ISSUE 10: in-kernel gather via scalar prefetch.
+# In-kernel gather: one manual DMA per neighbor row.
 #
-# Grid = (R,): one program per neighbor slot.  The ids vector is the
-# scalar-prefetch argument, so every BlockSpec index map receives it and the
-# db row map ``(max(ids[j], 0), 0)`` resolves *before* program j runs — the
-# pipeline overlaps row j+1's DMA with row j's compute.  Blocks are (1, d)
-# rows; the reduction is ``jnp.sum(..., axis=-1)`` on the (1, d) block, the
-# exact reduction shape the XLA reference path uses per row, which is what
-# makes fp32 ``fused`` bit-identical to ``xla`` (asserted in
-# tests/test_kernel_equiv.py).
+# The ids vector is the scalar-prefetch argument, so it sits in SMEM when the
+# body starts.  ``db`` stays in HBM (``memory_space=ANY``); the body issues
+# one ``make_async_copy`` per id into an (R, k, W) VMEM scratch, all R in
+# flight before the first wait, then scores the block.  Mosaic accepts a
+# one-row DMA only from an HBM array whose rows are exactly one 128-lane
+# tile row (fp32); wider or odd ``d`` is therefore read through the lane-row
+# view built by ``lane_rows`` — k = ⌈d/128⌉ consecutive 128-wide rows per
+# base vector, zero-padded — which ``GateIndex`` caches once per index.
+# Interpret mode (the CPU test path) reads the plain (N, d) array, k = 1, so
+# the per-row reduction ``jnp.sum(..., axis=-1)`` runs over the same ``d``
+# elements as the XLA formulation in ``graphs/search.py`` and fp32 results
+# are bit-identical (asserted in tests/test_kernel_equiv.py).
+#
+# There is no int8 twin: int8 HBM rows are packed four to a sublane word, so
+# the smallest int8 DMA Mosaic accepts is 8 rows (1 KB at d = 128, twice
+# the fp32 row), which cancels the byte cut the codebook exists for.
+# ``kernel="fused_q8"`` scores its codes with XLA on every platform.
+
+LANES = 128
 
 
-def _rows_l2_kernel(ids_ref, db_ref, q_ref, out_ref):
-    j = pl.program_id(0)
-    v = db_ref[...].astype(jnp.float32)          # (1, d) gathered row
-    q = q_ref[...].astype(jnp.float32)           # (1, d)
-    d = jnp.sum((v - q) ** 2, axis=-1)           # (1,)
-    out_ref[0, 0] = jnp.where(ids_ref[j] >= 0, d[0], INF)
+def lane_rows(db: jax.Array) -> jax.Array:
+    """(N, d) → (N·k, 128) view, k = ⌈d/128⌉, zero-padded: the layout the
+    TPU kernel DMAs rows from.  Free for d = 128; an O(N·d) copy otherwise,
+    so serving callers build it once (``GateIndex`` caches it)."""
+    n, d = db.shape
+    if d == LANES:
+        return db
+    pad = (-d) % LANES
+    return jnp.pad(db, ((0, 0), (0, pad))).reshape(n * ((d + pad) // LANES),
+                                                     LANES)
 
 
-def _rows_cos_kernel(ids_ref, db_ref, inv_ref, qn_ref, out_ref):
-    j = pl.program_id(0)
-    v = db_ref[...].astype(jnp.float32)          # (1, d)
-    vn = v * inv_ref[0, 0]                       # precomputed 1/‖v‖
-    d = 1.0 - jnp.sum(vn * qn_ref[...], axis=-1)
-    out_ref[0, 0] = jnp.where(ids_ref[j] >= 0, d[0], INF)
+def _rows_kernel(ids_ref, db_hbm, q_ref, *rest, cosine):
+    if cosine:
+        inv_ref, out_ref, buf, sem = rest
+    else:
+        out_ref, buf, sem = rest
+    R, k, _ = buf.shape
 
+    def copy(j):
+        row = pl.multiple_of(jnp.maximum(ids_ref[j], 0) * k, k)
+        return pltpu.make_async_copy(
+            db_hbm.at[pl.ds(row, k)], buf.at[j], sem.at[0]
+        )
 
-def _row_spec(ids_dim):
-    # index_map receives (grid idx j, prefetched ids); max() keeps invalid
-    # (-1) slots DMA-safe — they fetch row 0 and the mask discards the value.
-    if ids_dim is None:  # broadcast row (the query): always block (0, 0)
-        return lambda j, ids: (0, 0)
-    return lambda j, ids: (jnp.maximum(ids[j], 0), 0)
+    # invalid (-1) slots — padding and already-visited neighbors — read
+    # nothing; their scratch rows hold stale data the wrapper masks to +inf
+    def start(j, c):
+        @pl.when(ids_ref[j] >= 0)
+        def _():
+            copy(j).start()
+        return c
+
+    def wait(j, c):
+        @pl.when(ids_ref[j] >= 0)
+        def _():
+            copy(j).wait()
+        return c
+
+    jax.lax.fori_loop(0, R, start, 0)
+    jax.lax.fori_loop(0, R, wait, 0)
+    v = buf[...].astype(jnp.float32)                 # (R, k, W)
+    if cosine:
+        t = (v * inv_ref[...]) * q_ref[...]          # precomputed 1/‖v‖
+    else:
+        t = (v - q_ref[...]) ** 2
+    # fold the k lane rows first (elementwise), then one lane reduction
+    d = jnp.sum(jnp.sum(t, axis=1), axis=-1, keepdims=True)   # (R, 1)
+    out_ref[...] = 1.0 - d if cosine else d
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_rows_dist(
     ids: jax.Array,   # (R,) int32 row ids, -1 = invalid
-    db: jax.Array,    # (N, d) base vectors (d lane-aligned on real TPU)
-    q: jax.Array,     # (d,) fp32 query (pre-normalized under cosine)
+    db: jax.Array,    # (N·k, W) row view: ``lane_rows(db)`` on TPU, or the
+                      # plain (N, d) array (k = 1)
+    q: jax.Array,     # (k·W,) fp32 query (pre-normalized under cosine)
     inv_norms=None,   # (N,) fp32 1/‖row‖ — presence selects the cosine body
     *,
     interpret: bool = False,
 ) -> jax.Array:
     """(R,) masked distances with the gather done inside the kernel."""
-    R = ids.shape[0]
-    D = db.shape[1]
-    ids = ids.astype(jnp.int32)
-    if inv_norms is None:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(R,),
-            in_specs=[
-                pl.BlockSpec((1, D), _row_spec("db")),
-                pl.BlockSpec((1, D), _row_spec(None)),
-            ],
-            out_specs=pl.BlockSpec((1, 1), lambda j, ids: (j, 0)),
-        )
-        out = pl.pallas_call(
-            _rows_l2_kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((R, 1), jnp.float32),
-            interpret=interpret,
-        )(ids, db, q[None, :])
-    else:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(R,),
-            in_specs=[
-                pl.BlockSpec((1, D), _row_spec("db")),
-                pl.BlockSpec((1, 1), _row_spec("inv")),
-                pl.BlockSpec((1, D), _row_spec(None)),
-            ],
-            out_specs=pl.BlockSpec((1, 1), lambda j, ids: (j, 0)),
-        )
-        out = pl.pallas_call(
-            _rows_cos_kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((R, 1), jnp.float32),
-            interpret=interpret,
-        )(ids, db, inv_norms[:, None], q[None, :])
-    return out[:, 0]
-
-
-def _rows_q8_l2_kernel(ids_ref, codes_ref, scale_ref, zero_ref, q_ref, out_ref):
-    j = pl.program_id(0)
-    nb = scale_ref.shape[1]
-    dp = codes_ref.shape[1]
-    blk = dp // nb
-    c = codes_ref[...].reshape(nb, blk).astype(jnp.float32)
-    v = (c * scale_ref[...].reshape(nb, 1)
-         + zero_ref[...].reshape(nb, 1)).reshape(1, dp)
-    d = jnp.sum((v - q_ref[...]) ** 2, axis=-1)
-    out_ref[0, 0] = jnp.where(ids_ref[j] >= 0, d[0], INF)
-
-
-def _rows_q8_cos_kernel(
-    ids_ref, codes_ref, scale_ref, zero_ref, inv_ref, qn_ref, out_ref
-):
-    j = pl.program_id(0)
-    nb = scale_ref.shape[1]
-    dp = codes_ref.shape[1]
-    blk = dp // nb
-    c = codes_ref[...].reshape(nb, blk).astype(jnp.float32)
-    v = (c * scale_ref[...].reshape(nb, 1)
-         + zero_ref[...].reshape(nb, 1)).reshape(1, dp)
-    vn = v * inv_ref[0, 0]
-    d = 1.0 - jnp.sum(vn * qn_ref[...], axis=-1)
-    out_ref[0, 0] = jnp.where(ids_ref[j] >= 0, d[0], INF)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def gather_rows_dist_q8(
-    ids: jax.Array,     # (R,) int32 row ids, -1 = invalid
-    codes: jax.Array,   # (N, nb·blk) int8 — block-padded at build time
-    scale: jax.Array,   # (N, nb) fp32
-    zero: jax.Array,    # (N, nb) fp32
-    q: jax.Array,       # (nb·blk,) fp32 query padded to the code width
-    inv_norms=None,     # (N,) fp32 — presence selects the cosine body
-    *,
-    interpret: bool = False,
-) -> jax.Array:
-    """(R,) masked *approximate* distances from int8 rows, dequantized
-    in-register.  Padded dims dequantize to exactly 0.0 (integer zero-point,
-    see repro.quant) so they contribute nothing."""
-    R = ids.shape[0]
-    Dp = codes.shape[1]
-    nb = scale.shape[1]
-    ids = ids.astype(jnp.int32)
-    if inv_norms is None:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(R,),
-            in_specs=[
-                pl.BlockSpec((1, Dp), _row_spec("db")),
-                pl.BlockSpec((1, nb), _row_spec("scale")),
-                pl.BlockSpec((1, nb), _row_spec("zero")),
-                pl.BlockSpec((1, Dp), _row_spec(None)),
-            ],
-            out_specs=pl.BlockSpec((1, 1), lambda j, ids: (j, 0)),
-        )
-        out = pl.pallas_call(
-            _rows_q8_l2_kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((R, 1), jnp.float32),
-            interpret=interpret,
-        )(ids, codes, scale, zero, q[None, :])
-    else:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(R,),
-            in_specs=[
-                pl.BlockSpec((1, Dp), _row_spec("db")),
-                pl.BlockSpec((1, nb), _row_spec("scale")),
-                pl.BlockSpec((1, nb), _row_spec("zero")),
-                pl.BlockSpec((1, 1), _row_spec("inv")),
-                pl.BlockSpec((1, Dp), _row_spec(None)),
-            ],
-            out_specs=pl.BlockSpec((1, 1), lambda j, ids: (j, 0)),
-        )
-        out = pl.pallas_call(
-            _rows_q8_cos_kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((R, 1), jnp.float32),
-            interpret=interpret,
-        )(ids, codes, scale, zero, inv_norms[:, None], q[None, :])
-    return out[:, 0]
+    R0 = ids.shape[0]
+    W = db.shape[1]
+    k = q.shape[0] // W
+    # whole sublane tiles of slots: Mosaic cannot lay out a (1, k·W) block
+    R = -(-R0 // 8) * 8
+    ids = jnp.pad(ids.astype(jnp.int32), (0, R - R0), constant_values=-1)
+    const = lambda nd: (lambda i, ids: (0,) * nd)  # noqa: E731
+    in_specs = [
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec((k, W), const(2)),
+    ]
+    operands = [ids, db, q.astype(jnp.float32).reshape(k, W)]
+    cosine = inv_norms is not None
+    if cosine:
+        in_specs.append(pl.BlockSpec((R, 1, 1), const(3)))
+        operands.append(inv_norms[jnp.maximum(ids, 0)].reshape(R, 1, 1))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(1,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((R, 1), const(2)),
+        scratch_shapes=[
+            pltpu.VMEM((R, k, W), db.dtype),
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_rows_kernel, cosine=cosine),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, 1), jnp.float32),
+        interpret=interpret,
+    )(*operands)
+    return jnp.where(ids >= 0, out[:, 0], INF)[:R0]

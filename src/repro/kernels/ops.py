@@ -15,10 +15,8 @@ from repro.kernels import twotower_score as _tt
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    # no except: a backend that fails to start must raise, not read as "CPU"
+    return jax.default_backend() == "tpu"
 
 
 def l2dist(q, c, *, mode: str = "auto", **kw):

@@ -16,6 +16,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_reduced
 from repro.models.model import build_model
 from repro.obs import MetricsExporter
@@ -52,6 +53,7 @@ def main():
                          "after the run finishes")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     exporter = None
     if args.metrics_port is not None:

@@ -35,6 +35,7 @@ from typing import Optional, Sequence, Union
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.gate_index import GateIndex
 from repro.feedback.qlog import QueryLog, ShadowOversearch
 from repro.graphs.params import SearchParams
@@ -384,10 +385,10 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                          "loop (Ctrl-C exits early)")
     ap.add_argument("--kernel", default="xla",
                     choices=("xla", "fused", "fused_q8"),
-                    help="distance kernel (ISSUE 10): fused = in-kernel "
-                         "gather (bit-identical fp32; falls back to the "
-                         "matched XLA formulation off-TPU), fused_q8 = int8 "
-                         "codebook + exact rerank")
+                    help="distance kernel: fused = in-kernel gather on "
+                         "TPU (bit-identical fp32; the matched XLA "
+                         "formulation elsewhere), fused_q8 = int8 codebook "
+                         "scored by XLA + exact rerank")
     ap.add_argument("--kernel-interpret", action="store_true",
                     help="run Pallas kernel bodies in interpret mode "
                          "(CPU debugging; slow)")
@@ -408,6 +409,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                          "(0 = only via POST /reload)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # the daemon itself must be fully migrated to the SearchParams API: any
     # deprecated-kwarg use from within repro.* is a bug here, not a warning

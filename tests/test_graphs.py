@@ -50,6 +50,31 @@ def test_nsg_connectivity(small_db, small_nsg):
     assert seen.all(), f"{(~seen).sum()} nodes unreachable from medoid"
 
 
+def test_repair_attaches_each_component_once():
+    """Connectivity repair attaches an unreachable component through one
+    edge and then counts everything that node reaches as reachable (NSG
+    tree_grow) — not one repair edge per unreachable node."""
+    from repro.graphs.nsg import _repair_connectivity
+
+    rng = np.random.default_rng(0)
+    db = np.concatenate([
+        rng.standard_normal((10, 4)), 50.0 + rng.standard_normal((10, 4)),
+    ]).astype(np.float32)
+    ring = lambda lo: [[lo + (i + 1) % 10, -1] for i in range(10)]  # noqa: E731
+    nbrs = np.asarray(ring(0) + ring(10), np.int32)
+    out = _repair_connectivity(db, nbrs.copy(), 0)
+    added = int((out >= 0).sum() - (nbrs >= 0).sum())
+    assert added == 1
+    seen = np.zeros(20, bool)
+    stack, seen[0] = [0], True
+    while stack:
+        for v in out[stack.pop()]:
+            if v >= 0 and not seen[v]:
+                seen[v] = True
+                stack.append(int(v))
+    assert seen.all()
+
+
 def test_nsg_degree_capped(small_nsg):
     assert (small_nsg.neighbors >= -1).all()
     assert small_nsg.neighbors.shape[1] == small_nsg.R

@@ -60,10 +60,11 @@ import jax
 from repro.configs import get_reduced
 from repro.configs.base import ShapeSpec
 from repro.launch.cells import build_cell, lower_cell
+from repro.launch.mesh import make_host_mesh
 cfg = get_reduced("llama3-8b")
 kind = "train" if "{shape_name}" == "train_4k" else "decode"
 shape = ShapeSpec("{shape_name}", kind, 128, 8)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_host_mesh((2, 2), ("data", "model"))
 cell = build_cell(cfg, shape, mesh, num_microbatches=2)
 with mesh:
     compiled = lower_cell(cell).compile()
@@ -83,12 +84,13 @@ import jax
 import dataclasses
 from repro.launch import gate_cell
 from repro.launch.cells import lower_cell
+from repro.launch.mesh import make_host_mesh
 # shrink the registered shape so a 4-device host mesh compiles fast
 gs = gate_cell.GATE_SHAPES["search_1b"]
 gate_cell.GATE_SHAPES["tiny"] = dataclasses.replace(
     gs, name="tiny", n_total=4096, d=32, R=8, batch=16, beam_width=8,
     num_hops=8, k=4)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_host_mesh((2, 2), ("data", "model"))
 cell = gate_cell.build_gate_cell("tiny", mesh)
 with mesh:
     compiled = lower_cell(cell).compile()
