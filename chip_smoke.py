@@ -46,12 +46,12 @@ sys.path.insert(0, os.path.join(HERE, "src"))
 
 # GATE's recall@10 at the top rung in a CPU rehearsal of this configuration
 # at N = FLOOR_N, the largest N the CPU builds in about ten minutes, was
-# 0.6937 (``JAX_PLATFORMS=cpu python chip_smoke.py --n 200000``, 545 s on
+# 0.6577 (``JAX_PLATFORMS=cpu python chip_smoke.py --n 200000``, 745 s on
 # an 8-core host); the floor is that less 0.05.  On this data recall falls
 # with N at a fixed beam, so the floor holds at FLOOR_N and is checked
 # there, not at 1M.
 FLOOR_N = 200_000
-RECALL_FLOOR = 0.6437
+RECALL_FLOOR = 0.6077
 BASELINE_MARGIN = 0.02     # GATE may trail the medoid baseline by this much
 FUSED_ID_MATCH = 0.99      # fused vs xla: share of identical id slots
 Q8_RECALL_SLACK = 0.005    # fused_q8 vs xla recall@10
@@ -127,7 +127,10 @@ def main(argv=None) -> int:
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--batch", type=int, default=64,
                     help="queries per served request")
-    ap.add_argument("--eval", type=int, default=256,
+    # 2,048 queries: the fused_q8 - xla recall gap is noise of about
+    # 0.0024 (sd) per 256 queries at 100k rows, which a 0.005 check over
+    # 256 queries trips in about one run in twenty
+    ap.add_argument("--eval", type=int, default=2048,
                     help="queries for the recall checks")
     args = ap.parse_args(argv)
     t_start = time.perf_counter()
@@ -250,7 +253,7 @@ def main(argv=None) -> int:
           f"fused ids match xla on {match:.4f} of slots (>= {FUSED_ID_MATCH})")
     r_x = recall_at_k(out["xla"], truth, K)
     r_q = recall_at_k(out["fused_q8"], truth, K)
-    check(r_q >= r_x - Q8_RECALL_SLACK,
+    check(abs(r_q - r_x) <= Q8_RECALL_SLACK,
           f"fused_q8 recall@{K} {r_q:.4f} within {Q8_RECALL_SLACK} of xla "
           f"{r_x:.4f}")
 
