@@ -3,9 +3,9 @@ TPU roofline estimates per kernel (from tile shapes and the v5e model —
 197 TFLOP/s bf16, 819 GB/s HBM).
 
 It also covers the bandwidth-optimized search path (``gather_rows_dist``,
-the scalar-prefetch in-kernel gather, and the int8 codebook scored by XLA)
-plus an end-to-end xla/fused/fused_q8 serving gate (imported from
-bench_qps).  Their combined results are written to
+the in-kernel gather) plus an end-to-end xla/fused/fused_q8 serving gate
+(imported from bench_qps); ``fused_q8`` scores with XLA, so it has no
+micro section.  Their combined results are written to
 ``BENCH_kernels.json`` — the artifact CI uploads.  ``--smoke`` shrinks
 every shape for the CI lane.
 """
@@ -24,7 +24,6 @@ from repro.kernels.gather_dist import gather_dist, gather_rows_dist
 from repro.kernels.l2dist import l2dist
 from repro.kernels.topk import topk_min
 from repro.kernels.twotower_score import twotower_score
-from repro.quant import quantize_db
 
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
@@ -142,40 +141,6 @@ def run(mode: str = "quick", e2e: bool = True):
         "tpu_bound": "memory",
     }
 
-    # int8 codebook rows, dequantized and scored by XLA — no
-    # int8 row DMA pays off on TPU; the search path reranks the top
-    # k*rerank_mult candidates exactly in fp32.
-    qdb = quantize_db(np.asarray(gdb))
-    codes = jnp.asarray(qdb.codes)
-    scale = jnp.asarray(qdb.scale)
-    zero = jnp.asarray(qdb.zero)
-    nb = qdb.n_blocks
-
-    @jax.jit
-    def xla_rows_q8(ids, codes, scale, zero, q):  # the fused_q8 scorer
-        safe = jnp.maximum(ids, 0)
-        c = codes[safe].astype(jnp.float32).reshape(ids.shape[0], nb, -1)
-        v = (c * scale[safe][:, :, None] + zero[safe][:, :, None]
-             ).reshape(ids.shape[0], -1)
-        d = jnp.sum((v - q) ** 2, axis=-1)
-        return jnp.where(ids >= 0, d, INF)
-
-    t_q8 = _time(xla_rows_q8, gids, codes, scale, zero, gq)
-    got_q8 = np.asarray(xla_rows_q8(gids, codes, scale, zero, gq))
-    valid = gids_np >= 0
-    rel = np.abs(got_q8[valid] - want[valid]) / np.maximum(want[valid], 1e-6)
-    bytes_q8 = float(R * (codes.shape[1] + 8 * nb) + 4 * R)
-    results["q8_rows"] = {
-        "interpret_ok": bool(np.all(rel < 0.05)),   # approximate by design
-        "max_rel_err_vs_fp32": float(rel.max()),
-        "cpu_ref_s": t_q8,
-        "bytes": bytes_q8,
-        "hbm_traffic_ratio_vs_fused_fp32": bytes_fused / bytes_q8,
-        "quant": {"block": qdb.block, "n_blocks": nb},
-        "tpu_memory_s": bytes_q8 / HBM_BW,
-        "tpu_bound": "memory",
-    }
-
     # twotower_score at entry-selection shapes (B queries x 512 hubs)
     Bq, H, Do = 4096, 512, 128
     zq = jnp.asarray(rng.standard_normal((Bq, Do)).astype(np.float32))
@@ -212,7 +177,6 @@ def run(mode: str = "quick", e2e: bool = True):
         "mode": mode,
         "micro": {
             "gather_rows_dist": results["gather_rows_dist"],
-            "q8_rows": results["q8_rows"],
         },
     }
     if e2e:

@@ -43,7 +43,11 @@ from repro.graphs.params import (
     resolve_search_params,
     warn_deprecated_kwarg,
 )
-from repro.graphs.search import SearchResult, batched_search
+from repro.graphs.search import (
+    SearchResult,
+    batched_search,
+    lower_batched_search,
+)
 from repro.obs import (
     SearchTelemetry,
     call_telemetry_sink,
@@ -307,6 +311,22 @@ class GateIndex:
                 kw["db_lane"] = dev["db_lane"]
         return kw
 
+    def _search_args(self, queries, entries, params: SearchParams):
+        """``(args, kwargs)`` of the ``batched_search`` call ``search``
+        makes; ``lower_search`` lowers the same call."""
+        dev = self._device()
+        args = (dev["db"], dev["neighbors"], jnp.asarray(queries), entries,
+                params)
+        return args, self._search_kwargs(params)
+
+    def lower_search(self, queries: np.ndarray, *, params: SearchParams):
+        """The program ``search`` runs for these queries and params,
+        lowered (``jax.stages.Lowered``) and not run."""
+        args, kw = self._search_args(
+            queries, self.select_entries(queries), params
+        )
+        return lower_batched_search(*args, **kw)
+
     def select_entries(self, queries: jax.Array, *, instrument: bool = False):
         """(B, probe_width) base-graph entry ids chosen by the model.
 
@@ -513,20 +533,16 @@ class GateIndex:
             telemetry_sink = _UNSET if record else None
         params = resolve_search_params("GateIndex.search", params, legacy, k=k)
         sink = registry_sink if telemetry_sink is _UNSET else telemetry_sink
-        dev = self._device()
         if not params.instrument:
-            entries = self.select_entries(queries)
-            return batched_search(
-                dev["db"], dev["neighbors"], jnp.asarray(queries), entries,
-                params=params, **self._search_kwargs(params),
+            args, kw = self._search_args(
+                queries, self.select_entries(queries), params
             )
+            return batched_search(*args, **kw)
         with span("gate.search", queries=len(queries),
                   beam_width=params.beam_width):
             entries, nav_hops = self.select_entries(queries, instrument=True)
-            res, tele = batched_search(
-                dev["db"], dev["neighbors"], jnp.asarray(queries), entries,
-                params=params, **self._search_kwargs(params),
-            )
+            args, kw = self._search_args(queries, entries, params)
+            res, tele = batched_search(*args, **kw)
         tele = tele._replace(nav_hops=nav_hops)
         if sink is not None:
             sink(tele, params=params, where="GateIndex.search")

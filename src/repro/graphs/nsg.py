@@ -84,7 +84,8 @@ def _mrng_prune_batch(node_vecs, cand_ids, cand_vecs, R):
         )[:, 0, :]  # (B, P)
         supp_new = d_to_pick < d_node
         suppressed = suppressed | jnp.where(ok[:, None], supp_new, False)
-        suppressed = suppressed.at[jnp.arange(B), j].set(True)
+        # a select, not a batched scatter (see graphs.search._set_at)
+        suppressed = suppressed | (jnp.arange(P)[None, :] == j[:, None])
         n_sel = n_sel + ok.astype(jnp.int32)
         return suppressed, selected, n_sel
 

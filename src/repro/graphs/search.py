@@ -65,6 +65,18 @@ def _merge_top_l(ids_a, d_a, exp_a, ids_b, d_b):
     return ids[order][:L], d[order][:L], expanded[order][:L]
 
 
+def _set_at(x, i, v):
+    """``x.at[i].set(v)`` for a 1-D loop carry, written as a select.
+
+    Under ``vmap`` the scatter form becomes a batched scatter, which the TPU
+    compiler flattens at large batches.  Compiled that way, the beam-64
+    search at a batch of 1,024 or more ran nearly every query to its hop
+    cap on a v5e, with ids that depended on the batch size.  A compare
+    against an iota is one elementwise pass over the carry and needs no
+    scatter."""
+    return jnp.where(jnp.arange(x.shape[0]) == i, v, x)
+
+
 def _rerank_exact(beam_ids, beam_d, evals, rerank, exact_dist):
     """q8 epilogue: re-score the first ``rerank`` beam slots (already sorted
     best-first by approximate distance) with the exact fp32 formulation and
@@ -269,8 +281,8 @@ def beam_search_single(
             masked = jnp.where(expanded | (beam_ids < 0), INF, beam_d)
             j = jnp.argmin(masked)
             p = beam_ids[j]
-            expanded = expanded.at[j].set(True)
-            ring = ring.at[hops % visited_ring].set(p)
+            expanded = _set_at(expanded, j, True)
+            ring = _set_at(ring, hops % visited_ring, p)
             nbrs = neighbors[jnp.maximum(p, 0)]  # (R,)
             # dedup against beam + visited ring
             seen_beam = jnp.any(nbrs[:, None] == beam_ids[None, :], axis=1)
@@ -310,11 +322,11 @@ def beam_search_single(
         masked = jnp.where(expanded | (beam_ids < 0), INF, beam_d)
         j = jnp.argmin(masked)
         p = beam_ids[j]
-        expanded = expanded.at[j].set(True)
+        expanded = _set_at(expanded, j, True)
         slot = hops % visited_ring
         # a live id overwritten = node can silently be re-scored later
         evictions = evictions + (ring[slot] >= 0).astype(jnp.int32)
-        ring = ring.at[slot].set(p)
+        ring = _set_at(ring, slot, p)
         nbrs = neighbors[jnp.maximum(p, 0)]  # (R,)
         seen_beam = jnp.any(nbrs[:, None] == beam_ids[None, :], axis=1)
         seen_ring = jnp.any(nbrs[:, None] == ring[None, :], axis=1)
@@ -460,6 +472,27 @@ def batched_search(
     )
 
 
+def lower_batched_search(
+    db: jax.Array,
+    neighbors: jax.Array,
+    queries: jax.Array,
+    entry_ids: jax.Array,
+    params: SearchParams,
+    *,
+    inv_norms: Optional[jax.Array] = None,
+    quant=None,
+    db_lane: Optional[jax.Array] = None,
+):
+    """``batched_search``'s program for these arguments, lowered and not
+    run: the same jit entry and operands, so ``.compile().as_text()`` is the
+    program such a call runs (for example, whether it holds a Pallas
+    kernel)."""
+    return _batched_search.lower(
+        db, neighbors, queries, entry_ids, inv_norms, quant, db_lane,
+        params=params,
+    )
+
+
 def search_jit_cache_size() -> int:
     """Number of distinct compiled ``batched_search`` programs (one per
     (shapes, ``SearchParams``) combination).  The adaptive-serving
@@ -544,7 +577,9 @@ def beam_search_fixed(
         else:
             _, j = jax.lax.top_k(-masked, E)   # E best unexpanded
         p = beam_ids[j]                         # (E,)
-        expanded = expanded.at[j].set(True)
+        expanded = expanded | jnp.any(
+            jnp.arange(L)[:, None] == j[None, :], axis=1
+        )
         start = ((h * E) % visited_ring,)
         if count:
             old = jax.lax.dynamic_slice(ring, start, (E,))

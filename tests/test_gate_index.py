@@ -86,6 +86,23 @@ def test_search_kwargs_caches_lane_aligned_db(monkeypatch):
     )
 
 
+@pytest.mark.parametrize("kernel", ["xla", "fused_q8"])
+def test_lower_search_is_the_served_program(built_index, kernel):
+    """``lower_search`` lowers the call ``search`` makes: its compiled
+    program, fed the operands ``search`` passes, returns the same ids."""
+    from repro.graphs.params import SearchParams
+
+    idx, eq = built_index
+    sp = SearchParams(k=10, beam_width=32, max_hops=128, kernel=kernel)
+    compiled = idx.lower_search(eq, params=sp).compile()
+    args, kw = idx._search_args(eq, idx.select_entries(eq), sp)
+    out = compiled(*args[:4], kw.get("inv_norms"), kw.get("quant"),
+                   kw.get("db_lane"))
+    np.testing.assert_array_equal(
+        np.asarray(out.ids), np.asarray(idx.search(eq, params=sp).ids)
+    )
+
+
 def test_ablation_variants_build():
     """GATE w/o H / w/o FE / w/o L all construct and search (Table 4)."""
     from repro.graphs.nsg import build_nsg

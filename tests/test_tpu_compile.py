@@ -133,3 +133,30 @@ def test_batched_search_q8_compiles(one_chip, on_tpu, d):
         *_search_args(one_chip, n, d), None, quant, params=params
     ).compile()
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("beam", [64, 128])
+def test_batched_search_loop_has_no_scatter(one_chip, beam):
+    """At a batch of 2,048 the compiler flattens a vmapped scatter.  The
+    beam-64 search compiled that way ran nearly every query to its hop cap
+    on a v5e.  The loop writes its carries with selects."""
+    params = SearchParams(k=10, beam_width=beam, max_hops=4 * beam)
+    compiled = _batched_search.lower(
+        *_search_args(one_chip, 100_000, 128, b=2048), params=params
+    ).compile()
+    assert " scatter(" not in compiled.as_text()
+
+
+def test_nsg_prune_has_no_scatter(one_chip):
+    """The NSG prune's suppression update runs at the build's batch of
+    1,024 rows; it too is a select, not a batched scatter."""
+    from repro.graphs.nsg import _mrng_prune_batch
+
+    b, p, d, r = 1024, 96, 128, 32
+    compiled = jax.jit(_mrng_prune_batch, static_argnums=(3,)).lower(
+        _sds((b, d), jnp.float32, one_chip),
+        _sds((b, p), jnp.int32, one_chip),
+        _sds((b, p, d), jnp.float32, one_chip),
+        r,
+    ).compile()
+    assert " scatter(" not in compiled.as_text()
