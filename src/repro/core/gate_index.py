@@ -17,6 +17,7 @@ pair, leaving the underlying index untouched (paper §1).
 """
 from __future__ import annotations
 
+import functools
 import pickle
 import time
 from dataclasses import dataclass, field
@@ -62,6 +63,40 @@ from repro import quant as quantlib
 # "telemetry_sink not passed" marker: the default sink is registry_sink,
 # but an explicit None must mean "no side effects" (old record=False)
 _UNSET = object()
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("tower_cfg", "nav_start", "flat", "probe_width"),
+)
+def gate_select_entries(tower_params, queries, nav_reps, nav_neighbors,
+                        hub_ids, *, tower_cfg: TwoTowerConfig, nav_start: int,
+                        flat: bool, probe_width: int):
+    """Entry selection as one program: query tower, then either one fused
+    ``twotower_score`` over every hub and its argmax / top-``probe_width``
+    (``flat``: small hub sets), or the greedy cosine descent on the
+    navigation graph (large hub sets: no |V| scores per query); then the
+    hubs' base-graph ids, with each query's nav-graph descent length
+    (zeros on the flat path, which takes no hops).  Its XLA module is named
+    after this function, so a profiler trace finds it by
+    ``select_entries``."""
+    z_q = query_tower(tower_params, tower_cfg,
+                      jnp.asarray(queries, jnp.float32))
+    if flat:
+        from repro.kernels import ops
+
+        scores = ops.twotower_score(z_q, nav_reps)
+        if probe_width == 1:
+            hub_local = jnp.argmax(scores, axis=1)[:, None]
+        else:
+            _, hub_local = jax.lax.top_k(scores, probe_width)
+        nav_hops = jnp.zeros((hub_local.shape[0],), jnp.int32)
+    else:
+        hub_local, nav_hops = ng.descend(
+            ng.NavGraphDevice(nav_reps, nav_neighbors, nav_start), z_q,
+            probe_width=probe_width, instrument=True,
+        )
+    return hub_ids[hub_local], nav_hops
 
 
 @dataclass(frozen=True)
@@ -328,43 +363,21 @@ class GateIndex:
         return lower_batched_search(*args, **kw)
 
     def select_entries(self, queries: jax.Array, *, instrument: bool = False):
-        """(B, probe_width) base-graph entry ids chosen by the model.
-
-        Small hub sets: one fused twotower_score matmul over every hub
-        (kernels/twotower_score on TPU).  Large hub sets: greedy cosine
-        descent on the navigation graph (avoids |V| scores per query).
+        """(B, probe_width) base-graph entry ids chosen by the model, from
+        one jitted program (``gate_select_entries``).
 
         ``instrument=True`` additionally returns the per-query nav-graph
         descent length (zeros on the flat-score path, which takes no hops).
         """
         dev = self._device()
-        z_q = query_tower(
-            self.tower_params, self.tower_cfg,
-            jnp.asarray(queries, jnp.float32),
+        nav = dev["nav"]
+        entries, nav_hops = gate_select_entries(
+            self.tower_params, queries, nav.reps, nav.neighbors,
+            dev["hub_ids"], tower_cfg=self.tower_cfg, nav_start=nav.start,
+            flat=self.hubs.n <= self.gcfg.flat_score_max,
+            probe_width=self.gcfg.probe_width,
         )
-        w = self.gcfg.probe_width
-        nav_hops = None
-        if self.hubs.n <= self.gcfg.flat_score_max:
-            from repro.kernels import ops
-
-            scores = ops.twotower_score(z_q, dev["nav"].reps)
-            if w == 1:
-                hub_local = jnp.argmax(scores, axis=1)[:, None]
-            else:
-                _, hub_local = jax.lax.top_k(scores, w)
-            if instrument:
-                nav_hops = jnp.zeros((hub_local.shape[0],), jnp.int32)
-        else:
-            if instrument:
-                hub_local, nav_hops = ng.descend(
-                    dev["nav"], z_q, probe_width=w, instrument=True
-                )
-            else:
-                hub_local = ng.descend(dev["nav"], z_q, probe_width=w)
-        entries = dev["hub_ids"][hub_local]
-        if instrument:
-            return entries, nav_hops
-        return entries
+        return (entries, nav_hops) if instrument else entries
 
     def route_signals(self, queries: jax.Array, *, with_features: bool = False):
         """Per-query entry ids + hardness, from signals GATE computes anyway.
@@ -533,19 +546,23 @@ class GateIndex:
             telemetry_sink = _UNSET if record else None
         params = resolve_search_params("GateIndex.search", params, legacy, k=k)
         sink = registry_sink if telemetry_sink is _UNSET else telemetry_sink
-        if not params.instrument:
-            args, kw = self._search_args(
-                queries, self.select_entries(queries), params
-            )
-            return batched_search(*args, **kw)
-        with span("gate.search", queries=len(queries),
-                  beam_width=params.beam_width):
+        with span("gate.select_entries"):
             entries, nav_hops = self.select_entries(queries, instrument=True)
+        with span("gate.search.dispatch", queries=len(queries),
+                  beam_width=params.beam_width):
             args, kw = self._search_args(queries, entries, params)
-            res, tele = batched_search(*args, **kw)
+            out = batched_search(*args, **kw)
+        if not params.instrument:
+            return out
+        res, tele = out
         tele = tele._replace(nav_hops=nav_hops)
         if sink is not None:
-            sink(tele, params=params, where="GateIndex.search")
+            # the sink reads the telemetry on the host, so it waits for the
+            # device anyway; the wait gets a span of its own
+            with span("gate.search.device_wait"):
+                jax.block_until_ready((res, tele))
+            with span("gate.search.telemetry"):
+                sink(tele, params=params, where="GateIndex.search")
         return res, tele
 
     def search_routed(

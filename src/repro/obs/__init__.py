@@ -3,7 +3,8 @@
 Offline half (ISSUE 6):
   registry   — counters / gauges / fixed-bucket histograms; JSON +
                Prometheus-text export (``get_registry()``)
-  trace      — host-side ``span()`` / ``@traced`` → chrome://tracing JSONL
+  trace      — host-side ``span()`` → chrome://tracing JSONL and, while
+               ``jax.profiler`` traces, the profiler's host plane
                (``get_tracer()``)
   telemetry  — ``SearchTelemetry`` pytree accumulated inside the jitted
                search loops + host-side recording/warnings
@@ -51,7 +52,7 @@ from repro.obs.telemetry import (
     summarize,
     warn_on_ring_overflow,
 )
-from repro.obs.trace import Tracer, get_tracer, read_trace, span, traced
+from repro.obs.trace import Tracer, get_tracer, read_trace, span
 from repro.obs.window import RollingWindow
 
 __all__ = [
@@ -82,6 +83,5 @@ __all__ = [
     "route_buckets",
     "span",
     "summarize",
-    "traced",
     "warn_on_ring_overflow",
 ]

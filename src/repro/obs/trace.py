@@ -1,24 +1,38 @@
-"""Host-side spans → chrome://tracing-compatible JSONL.
+"""Host-side spans → chrome://tracing-compatible JSONL and the JAX profiler.
 
-``span(name)`` / ``@traced`` wrap the host phases (index build stages,
-prefill/decode, RAG retrieve, train steps).  Events are Trace Event Format
-"complete" events (``ph: "X"``) written one JSON object per line; the file
-opens with ``[`` so chrome://tracing / Perfetto load it directly (the trailing
-``]`` is optional in the format, which is what makes line-appending safe for
-crashing processes).
+``span(name)`` wraps the host phases (index build stages, the serving path,
+prefill/decode, RAG retrieve, train steps).  A span records while the tracer
+is started (``Tracer.start``) or while ``jax.profiler`` traces
+(``jax.profiler.start_trace``).  When it records it also enters a
+``jax.profiler.TraceAnnotation`` of the same name, so that under the profiler
+the span sits in the trace's host plane, on the device planes' clock.
 
-Disabled (the default) the span body costs one attribute load and a branch —
+Events are Trace Event Format "complete" events (``ph: "X"``), ``ts`` and
+``dur`` in µs from the tracer's origin ``Tracer.t0`` (a
+``time.perf_counter`` reading), each with its own ``id``, the ``parent`` span
+open on the same thread when it began, and the request id ``req``, which a
+span inherits from its parent unless it sets one.  They are kept in memory
+(``Tracer.events``); after ``start(path)`` they are also written one JSON
+object per line to ``path``, which opens with ``[`` so chrome://tracing /
+Perfetto load it directly (the trailing ``]`` is optional in the format,
+which is what makes line-appending safe for crashing processes).
+
+Off (the default) a span costs a couple of attribute loads and branches —
 no clock reads, no allocation of event dicts.
 """
 from __future__ import annotations
 
-import functools
+import itertools
 import json
 import os
 import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+_profiling = TraceAnnotation.is_enabled   # True while jax.profiler traces
 
 
 class Tracer:
@@ -28,14 +42,18 @@ class Tracer:
         self._events: List[dict] = []
         self._file = None
         self._path: Optional[str] = None
-        self._t0 = time.perf_counter()
+        self._ids = itertools.count(1)
+        self._local = threading.local()
+        # origin of ``ts``: an event spans t0 + ts/1e6 .. + dur/1e6 on the
+        # host's perf_counter clock
+        self.t0 = time.perf_counter()
 
     # -------------------------------------------------------------- control
     def start(self, path: Optional[str] = None) -> None:
         """Enable tracing; if ``path`` is given, stream events to it."""
         with self._lock:
             self._events.clear()
-            self._t0 = time.perf_counter()
+            self.t0 = time.perf_counter()
             if self._file is not None:
                 self._file.close()
                 self._file = None
@@ -57,37 +75,43 @@ class Tracer:
     def path(self) -> Optional[str]:
         return self._path
 
-    # -------------------------------------------------------------- record
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+    @property
+    def recording(self) -> bool:
+        return self.enabled or _profiling()
 
-    def emit(self, event: dict) -> None:
-        if not self.enabled:
-            return
+    # -------------------------------------------------------------- record
+    def _stack(self) -> list:
+        """This thread's open spans, as ``(id, req)``."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def complete_event(
+        self, name: str, start: float, end: float,
+        args: Optional[Dict[str, Any]] = None, *, req: Optional[int] = None,
+    ) -> None:
+        """Record ``name`` from ``start`` to ``end`` (``time.perf_counter``
+        seconds), with no parent, when recording.  Its start may lie on
+        another thread, and a profiler event cannot be back-dated, so it
+        goes to the tracer alone, never to the profiler's trace."""
+        if self.recording:
+            self._record(next(self._ids), name, start, end, args, req, None)
+
+    def _record(self, sid: int, name: str, start: float, end: float,
+                args: Optional[Dict[str, Any]], req: Optional[int],
+                parent: Optional[int]) -> None:
+        event = {
+            "name": name, "ph": "X", "ts": (start - self.t0) * 1e6,
+            "dur": (end - start) * 1e6, "pid": os.getpid(),
+            "tid": threading.get_ident(), "id": sid, "parent": parent,
+            "req": req, "args": args or {},
+        }
         with self._lock:
             self._events.append(event)
             if self._file is not None:
                 self._file.write(json.dumps(event) + ",\n")
                 self._file.flush()
-
-    def complete_event(
-        self, name: str, ts_us: float, dur_us: float,
-        args: Optional[Dict[str, Any]] = None,
-    ) -> None:
-        self.emit({
-            "name": name, "ph": "X", "ts": ts_us, "dur": dur_us,
-            "pid": os.getpid(), "tid": threading.get_ident(),
-            "args": args or {},
-        })
-
-    def instant(self, name: str, args: Optional[Dict[str, Any]] = None) -> None:
-        if not self.enabled:
-            return
-        self.emit({
-            "name": name, "ph": "i", "ts": self._now_us(), "s": "t",
-            "pid": os.getpid(), "tid": threading.get_ident(),
-            "args": args or {},
-        })
 
     # -------------------------------------------------------------- export
     def events(self) -> List[dict]:
@@ -125,37 +149,33 @@ def get_tracer() -> Tracer:
 
 
 @contextmanager
-def span(name: str, **attrs):
-    """Time a host-side phase; no-op (one branch) when tracing is disabled.
+def span(name: str, *, req: Optional[int] = None, **attrs):
+    """Time a host-side phase; a no-op when neither the tracer nor the JAX
+    profiler records.
 
-    Attribute values land in the trace event's ``args`` and must be
-    JSON-serializable.
+    ``req`` tags the span and the spans opened inside it with a request id.
+    Attribute values land in the trace event's ``args`` (and the profiler
+    event's metadata) and must be JSON-serializable.
     """
     t = _TRACER
-    if not t.enabled:
+    if not t.enabled and not _profiling():
         yield
         return
-    ts = t._now_us()
+    stack = t._stack()
+    parent, parent_req = stack[-1] if stack else (None, None)
+    if req is None:
+        req = parent_req
+    sid = next(t._ids)
+    stack.append((sid, req))
+    meta = attrs if req is None else {"req": req, **attrs}
+    start = time.perf_counter()
     try:
-        yield
+        with TraceAnnotation(name, **meta):
+            yield
     finally:
-        t.complete_event(name, ts, t._now_us() - ts, attrs or None)
-
-
-def traced(name: Optional[str] = None):
-    """Decorator form of ``span``; defaults to the function's qualname."""
-
-    def deco(fn):
-        sname = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapped(*a, **kw):
-            with span(sname):
-                return fn(*a, **kw)
-
-        return wrapped
-
-    return deco
+        end = time.perf_counter()
+        stack.pop()
+        t._record(sid, name, start, end, attrs, req, parent)
 
 
 def read_trace(path: str) -> List[dict]:

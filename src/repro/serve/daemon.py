@@ -7,7 +7,8 @@ Architecture — one worker thread, everything else observes it:
 
     submit() ──► queue ──► worker ──► index.search / pipeline()
                              │            (current ladder rung, instrumented)
-                             ├─► registry   search.latency_seconds, search.*
+                             ├─► registry   search.latency_seconds,
+                             │              daemon.queue_wait_seconds, search.*
                              ├─► window     summarize(tele) + latency_s
                              └─► controller step() (hysteresis ladder moves)
     exporter (daemon thread) ◄── /metrics /metrics.json /healthz /debug/telemetry
@@ -24,6 +25,7 @@ CLI smoke / load-drive mode:
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import queue
 import signal
@@ -50,9 +52,14 @@ from repro.obs import (
     RollingWindow,
     chain_sinks,
     get_registry,
+    get_tracer,
     registry_sink,
+    span,
     summarize,
 )
+
+# request ids, unique in the process: the ``req`` of a request's spans
+_REQUEST_IDS = itertools.count(1)
 
 
 @dataclass
@@ -260,7 +267,8 @@ class ServeDaemon:
     # -------------------------------------------------------------- requests
     def submit(self, req: SearchRequest) -> PendingResult:
         pending = PendingResult()
-        self._queue.put((req, pending))
+        self._queue.put((req, pending, next(_REQUEST_IDS),
+                         time.perf_counter()))
         if self._reg.enabled:
             self._reg.gauge(
                 "daemon.queue_depth", "requests waiting in the daemon queue"
@@ -276,35 +284,46 @@ class ServeDaemon:
 
     # ---------------------------------------------------------------- worker
     def _run(self) -> None:
+        tracer = get_tracer()
         while not self._stop.is_set():
             try:
-                req, pending = self._queue.get(timeout=0.05)
+                req, pending, rid, t_submit = self._queue.get(timeout=0.05)
             except queue.Empty:
                 continue
             t0 = time.perf_counter()
+            tracer.complete_event("daemon.queue_wait", t_submit, t0, req=rid)
+            if self._reg.enabled:
+                self._reg.histogram(
+                    "daemon.queue_wait_seconds",
+                    "request wait in the daemon queue, submit to dequeue",
+                    LATENCY_BUCKETS,
+                ).observe(t0 - t_submit)
             try:
-                result = self._serve_one(req)
+                with span("daemon.serve", req=rid, queries=len(req.queries)):
+                    result = self._serve_one(req)
+                    dt = time.perf_counter() - t0
+                    if self._reg.enabled:
+                        self._reg.histogram(
+                            "search.latency_seconds",
+                            "dequeue to answer (excludes queue wait)",
+                            LATENCY_BUCKETS,
+                        ).observe(dt)
+                        self._reg.counter(
+                            "daemon.requests", "served requests"
+                        ).inc()
+                        self._reg.counter(
+                            "daemon.queries", "served queries"
+                        ).inc(len(req.queries))
+                        self._reg.gauge(
+                            "daemon.queue_depth",
+                            "requests waiting in the daemon queue",
+                        ).set(self._queue.qsize())
             except BaseException as e:  # noqa: BLE001 — surfaced via future
                 self._reg.counter(
                     "daemon.errors", "requests that raised"
                 ).inc()
                 pending._fulfil(error=e)
                 continue
-            dt = time.perf_counter() - t0
-            if self._reg.enabled:
-                self._reg.histogram(
-                    "search.latency_seconds",
-                    "end-to-end request latency (daemon)",
-                    LATENCY_BUCKETS,
-                ).observe(dt)
-                self._reg.counter("daemon.requests", "served requests").inc()
-                self._reg.counter(
-                    "daemon.queries", "served queries"
-                ).inc(len(req.queries))
-                self._reg.gauge(
-                    "daemon.queue_depth",
-                    "requests waiting in the daemon queue",
-                ).set(self._queue.qsize())
             pending._fulfil(result=result)
 
     def _serve_one(self, req: SearchRequest):
@@ -328,23 +347,25 @@ class ServeDaemon:
             res, tele = self.index.search(
                 req.queries, params=self.controller.params.params(base)
             )
-        s = summarize(tele)
-        s["latency_s"] = time.perf_counter() - t0
-        self.window.push(s)
-        self._batches_served += 1
-        if self.router is not None:
-            if self.qlog is not None:
-                # the sink logged this batch; attach what's only known now
-                self.qlog.annotate_last(latency_s=s["latency_s"])
-                if self.shadow is not None:
-                    needed = self.shadow.maybe_label(req.queries, base)
-                    if needed is not None:
-                        self.qlog.annotate_last(needed_wide=needed)
-                if self._batches_served % self.window_log_every == 0:
-                    self.qlog.log_window(self.window, name="serve")
-            self.router.step()
-        elif self.adaptive:
-            self.controller.step()
+        with span("daemon.window"):
+            s = summarize(tele)
+            s["latency_s"] = time.perf_counter() - t0
+            self.window.push(s)
+            self._batches_served += 1
+            if self.router is not None:
+                if self.qlog is not None:
+                    # the sink logged this batch; attach what's only known
+                    # now
+                    self.qlog.annotate_last(latency_s=s["latency_s"])
+                    if self.shadow is not None:
+                        needed = self.shadow.maybe_label(req.queries, base)
+                        if needed is not None:
+                            self.qlog.annotate_last(needed_wide=needed)
+                    if self._batches_served % self.window_log_every == 0:
+                        self.qlog.log_window(self.window, name="serve")
+                self.router.step()
+            elif self.adaptive:
+                self.controller.step()
         return res, tele
 
 

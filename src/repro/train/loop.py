@@ -113,12 +113,11 @@ def instrument_step(step_fn, *, name: str = "train.step"):
         if not (tracer.enabled or reg.enabled):
             return step_fn(state, batch)
         t0 = time.perf_counter()
-        ts = tracer._now_us() if tracer.enabled else 0.0
         state, metrics = step_fn(state, batch)
         jax.block_until_ready(metrics)
         dt = time.perf_counter() - t0
         if tracer.enabled:
-            tracer.complete_event(name, ts, dt * 1e6)
+            tracer.complete_event(name, t0, t0 + dt)
         if reg.enabled:
             reg.counter("train.steps", "optimizer steps").inc()
             reg.histogram(

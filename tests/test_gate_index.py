@@ -103,6 +103,67 @@ def test_lower_search_is_the_served_program(built_index, kernel):
     )
 
 
+def _eager_entries(idx, queries):
+    """Entry selection composed op by op, as ``select_entries`` ran before
+    it was one jitted program."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import navgraph as ng
+    from repro.core.twotower import query_tower
+    from repro.kernels import ops
+
+    dev = idx._device()
+    z_q = query_tower(idx.tower_params, idx.tower_cfg,
+                      jnp.asarray(queries, jnp.float32))
+    w = idx.gcfg.probe_width
+    if idx.hubs.n <= idx.gcfg.flat_score_max:
+        scores = ops.twotower_score(z_q, dev["nav"].reps)
+        if w == 1:
+            hub_local = jnp.argmax(scores, axis=1)[:, None]
+        else:
+            _, hub_local = jax.lax.top_k(scores, w)
+        nav_hops = jnp.zeros((len(queries),), jnp.int32)
+    else:
+        hub_local, nav_hops = ng.descend(dev["nav"], z_q, probe_width=w,
+                                         instrument=True)
+    return dev["hub_ids"][hub_local], nav_hops
+
+
+@pytest.mark.parametrize("path", ["flat", "nav"])
+@pytest.mark.parametrize("probe_width", [1, 3])
+def test_select_entries_program_matches_eager(built_index, path,
+                                              probe_width):
+    """The jitted entry-selection program returns the ids (and nav hops)
+    of the eager composition, on the flat-score and nav-descent paths, in
+    one XLA module whose name holds ``select_entries``."""
+    import dataclasses
+
+    from repro.core.gate_index import gate_select_entries
+
+    base, eq = built_index
+    gcfg = dataclasses.replace(
+        base.gcfg, probe_width=probe_width,
+        flat_score_max=base.gcfg.flat_score_max if path == "flat" else 0)
+    idx = dataclasses.replace(base, gcfg=gcfg, _dev=None)
+    assert (idx.hubs.n <= gcfg.flat_score_max) == (path == "flat")
+    want_ids, want_hops = _eager_entries(idx, eq)
+    ids = idx.select_entries(eq)
+    got_ids, got_hops = idx.select_entries(eq, instrument=True)
+    assert ids.shape == (len(eq), probe_width)
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(want_ids))
+    np.testing.assert_array_equal(np.asarray(got_ids), np.asarray(want_ids))
+    np.testing.assert_array_equal(np.asarray(got_hops),
+                                  np.asarray(want_hops))
+    nav = idx._device()["nav"]
+    text = gate_select_entries.lower(
+        idx.tower_params, eq, nav.reps, nav.neighbors,
+        idx._device()["hub_ids"], tower_cfg=idx.tower_cfg,
+        nav_start=nav.start, flat=path == "flat",
+        probe_width=probe_width).as_text()
+    assert "module @jit_gate_select_entries" in text
+
+
 def test_ablation_variants_build():
     """GATE w/o H / w/o FE / w/o L all construct and search (Table 4)."""
     from repro.graphs.nsg import build_nsg

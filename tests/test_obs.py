@@ -1,6 +1,8 @@
 """Observability layer: metrics registry, spans/trace, search telemetry."""
+import glob
 import json
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -206,12 +208,8 @@ def test_span_and_trace_file(tmp_path):
         with trace_mod.span("phase.a", n=3):
             with trace_mod.span("phase.b"):
                 pass
-
-        @trace_mod.traced("decorated")
-        def f(x):
-            return x + 1
-
-        assert f(1) == 2
+        with trace_mod.span("decorated"):
+            pass
     finally:
         trace_mod._TRACER = old
         t.stop()
@@ -227,10 +225,65 @@ def test_span_and_trace_file(tmp_path):
 
 
 def test_span_disabled_is_noop():
+    t = obs.get_tracer()
+    assert not t.enabled  # module tracer disabled by default in tests
+    before = len(t.events())
+    with obs.span("nothing"):
+        with obs.span("nested", req=1):
+            pass
+    t.complete_event("nothing.complete", 0.0, 1.0)
+    assert len(t.events()) == before
+
+
+def test_span_records_under_the_profiler(tmp_path, monkeypatch):
+    """``jax.profiler.start_trace`` alone turns spans on: each lands in the
+    tracer's buffer, with its id, its parent and the request id it
+    inherits, and in the profiler's host plane under its own name."""
+    import repro.obs.trace as trace_mod
+
     t = Tracer()
-    with obs.span("nothing"):  # module tracer disabled by default in tests
+    monkeypatch.setattr(trace_mod, "_TRACER", t)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        t_in = time.perf_counter()
+        with obs.span("outer", req=7, queries=3):
+            with obs.span("inner"):
+                pass
+            with obs.span("own", req=8):
+                pass
+        t.complete_event("queued", t_in - 0.5, t_in, req=7)
+    finally:
+        jax.profiler.stop_trace()
+    assert not t.enabled
+    ev = {e["name"]: e for e in t.events()}
+    assert set(ev) == {"outer", "inner", "own", "queued"}
+    assert len({e["id"] for e in ev.values()}) == 4
+    assert ev["outer"]["parent"] is None and ev["outer"]["req"] == 7
+    assert ev["outer"]["args"] == {"queries": 3}
+    assert ev["inner"]["parent"] == ev["outer"]["id"]
+    assert ev["inner"]["req"] == 7            # inherited
+    assert ev["own"]["parent"] == ev["outer"]["id"] and ev["own"]["req"] == 8
+    assert ev["queued"]["parent"] is None and ev["queued"]["req"] == 7
+    # the host's perf_counter clock, from the public origin t0
+    start = t.t0 + ev["queued"]["ts"] / 1e6
+    assert start == pytest.approx(t_in - 0.5, abs=1e-6)
+    assert ev["queued"]["dur"] == pytest.approx(5e5, rel=1e-6)
+    o, i = ev["outer"], ev["inner"]
+    assert o["ts"] <= i["ts"] and i["ts"] + i["dur"] <= o["ts"] + o["dur"]
+
+    path = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True))[-1]
+    host = set()
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            host |= {e.name for line in plane.lines for e in line.events}
+    # spans reach the profiler; the back-dated complete event cannot
+    assert {"outer", "inner", "own"} <= host and "queued" not in host
+
+    # both off again: nothing more is recorded
+    with obs.span("after"):
         pass
-    assert t.events() == []
+    assert len(t.events()) == 4
 
 
 # --------------------------------------------------------- search telemetry

@@ -47,6 +47,7 @@ def test_daemon_serves_and_exports_metrics(tiny_index):
         # acceptance: latency histogram + hop/dist-eval counters on /metrics
         assert "search_latency_seconds_bucket" in text
         assert "search_latency_seconds_count 3" in text
+        assert "daemon_queue_wait_seconds_count 3" in text
         assert "search_hops_bucket" in text
         assert "search_dist_evals_bucket" in text
         assert "daemon_requests 3" in text
@@ -144,3 +145,49 @@ def test_daemon_fixed_mode_never_moves(tiny_index):
         assert len(daemon.window) > 0         # window still fills for SLOs
     finally:
         daemon.stop()
+
+
+def test_daemon_request_spans_under_the_profiler(tiny_index, tmp_path,
+                                                 monkeypatch):
+    """One request while ``jax.profiler`` traces: its queue wait, serve,
+    entry selection, dispatch, device wait, telemetry and window spans all
+    carry its request id and nest in time inside ``daemon.serve``."""
+    import jax
+
+    import repro.obs.trace as trace_mod
+
+    tracer = obs.Tracer()
+    monkeypatch.setattr(trace_mod, "_TRACER", tracer)
+    obs.get_registry().reset()
+    daemon = ServeDaemon(tiny_index, ladder=LADDER, level=0, batch_size=4,
+                         adaptive=False)
+    daemon.start(warmup=True)
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            daemon.search(np.asarray(tiny_index.db[:4]))
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        daemon.stop()
+    ev = {e["name"]: e for e in tracer.events()}
+    names = ("daemon.queue_wait", "daemon.serve", "gate.select_entries",
+             "gate.search.dispatch", "gate.search.device_wait",
+             "gate.search.telemetry", "daemon.window")
+    assert set(ev) == set(names)
+    assert len({ev[n]["req"] for n in names}) == 1
+    assert ev["daemon.serve"]["req"] is not None
+    assert ev["daemon.queue_wait"]["dur"] >= 0
+    serve = ev["daemon.serve"]
+    assert serve["args"] == {"queries": 4}
+    for n in names[2:]:
+        e = ev[n]
+        assert e["parent"] == serve["id"], n
+        assert serve["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= serve["ts"] + serve["dur"]
+    # in the order the worker runs them
+    starts = [ev[n]["ts"] for n in names[1:]]
+    assert starts == sorted(starts)
+    assert ev["daemon.queue_wait"]["ts"] <= serve["ts"]
+    hist = obs.get_registry().get("daemon.queue_wait_seconds")
+    assert hist.count == 1

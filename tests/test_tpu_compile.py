@@ -78,6 +78,29 @@ def test_twotower_score_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("flat", [True, False])
+def test_select_entries_compiles_at_serving_batch(one_chip, on_tpu, flat):
+    """Entry selection at the served batch (1,024 queries, d = 128, 64
+    hubs) is one program; on the flat path it runs the Pallas
+    ``twotower_score``."""
+    from repro.core.gate_index import gate_select_entries
+    from repro.core.twotower import TwoTowerConfig, init_params
+
+    cfg = TwoTowerConfig(d_p=128)
+    params = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    lowered = gate_select_entries.lower(
+        params, _sds((1024, 128), jnp.float32, one_chip),
+        _sds((64, cfg.d_out), jnp.float32, one_chip),
+        _sds((64, 8), jnp.int32, one_chip),
+        _sds((64,), jnp.int32, one_chip),
+        tower_cfg=cfg, nav_start=0, flat=flat, probe_width=1)
+    assert "module @jit_gate_select_entries" in lowered.as_text()
+    compiled = lowered.compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == flat
+
+
 def _search_args(one_chip, n, d, b=64, r=32):
     return (
         _sds((n, d), jnp.float32, one_chip),
