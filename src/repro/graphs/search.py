@@ -56,13 +56,21 @@ class SearchResult(NamedTuple):
 
 
 def _merge_top_l(ids_a, d_a, exp_a, ids_b, d_b):
-    """Merge beam (a) with candidates (b), keep L best unique by distance."""
+    """Merge beam (a) with candidates (b), keep L best unique by distance.
+
+    One stable sort keyed on distance carries the ids and flags with it: the
+    order of equal keys is ``jnp.argsort``'s.  Applying an argsort instead
+    takes three element gathers, which under ``vmap`` the TPU compiler
+    flattens into one-element gathers over all B·(L+R) slots, and those cost
+    the hop loop far more than the sort."""
     L = ids_a.shape[0]
-    ids = jnp.concatenate([ids_a, ids_b])
     d = jnp.concatenate([d_a, d_b])
+    ids = jnp.concatenate([ids_a, ids_b])
     expanded = jnp.concatenate([exp_a, jnp.zeros(ids_b.shape, jnp.bool_)])
-    order = jnp.argsort(d)
-    return ids[order][:L], d[order][:L], expanded[order][:L]
+    d, ids, expanded = jax.lax.sort(
+        (d, ids, expanded), num_keys=1, is_stable=True
+    )
+    return ids[:L], d[:L], expanded[:L]
 
 
 def _set_at(x, i, v):

@@ -1,12 +1,16 @@
 """Proximity graph substrate: KNN, NSG construction, beam search recall."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.data.synthetic import make_database, make_queries_in_dist
+from repro.graphs import search
 from repro.graphs.knn import exact_knn, knn_graph, medoid, recall_at_k
 from repro.graphs.nsg import build_nsg
+from repro.graphs.params import SearchParams
 from repro.graphs.search import (
+    INF,
     batched_search,
     beam_search_fixed,
     greedy_descent,
@@ -106,8 +110,6 @@ def test_beam_search_fixed_matches_while_variant(small_db, small_nsg):
         jnp.asarray(db), jnp.asarray(small_nsg.neighbors),
         jnp.asarray(queries), entries, beam_width=32, max_hops=64, k=5,
     )
-    import jax
-
     fixed = jax.vmap(
         lambda q, e: beam_search_fixed(
             jnp.asarray(db), jnp.asarray(small_nsg.neighbors), q, e,
@@ -220,3 +222,80 @@ def test_medoid_is_central(small_db):
     rand = rng.integers(0, len(db), 50)
     d_r = ((db[rand] - db.mean(0)) ** 2).sum(1).mean()
     assert d_m < d_r
+
+
+def _merge_top_l_argsort(ids_a, d_a, exp_a, ids_b, d_b):
+    """The merge as it was first written: an argsort applied by indexing."""
+    L = ids_a.shape[0]
+    ids = jnp.concatenate([ids_a, ids_b])
+    d = jnp.concatenate([d_a, d_b])
+    expanded = jnp.concatenate([exp_a, jnp.zeros(ids_b.shape, jnp.bool_)])
+    order = jnp.argsort(d)
+    return ids[order][:L], d[order][:L], expanded[order][:L]
+
+
+def _assert_bits_equal(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.dtype == b.dtype and a.shape == b.shape
+    if a.dtype == np.float32:
+        a, b = a.view(np.uint32), b.view(np.uint32)
+    np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("R", [32, 38])
+@pytest.mark.parametrize("L", [64, 128])
+def test_merge_top_l_matches_argsort_form(L, R):
+    """The one-sort merge equals the argsort-and-index merge bit for bit,
+    with distance ties inside and across the beam and the candidates,
+    ``-1`` ids and ``INF`` slots, vmapped over a batch."""
+    rng = np.random.default_rng(L * 100 + R)
+    B = 64
+    d_a = np.sort(rng.integers(0, 6, (B, L)).astype(np.float32), axis=1)
+    ids_a = rng.integers(0, 1000, (B, L)).astype(np.int32)
+    pad = rng.integers(0, L // 2, B)            # unfilled beam tails
+    tail = np.arange(L)[None, :] >= (L - pad)[:, None]
+    ids_a[tail], d_a[tail] = -1, INF
+    exp_a = (rng.random((B, L)) < 0.5) & (ids_a >= 0)
+    ids_b = rng.integers(0, 1000, (B, R)).astype(np.int32)
+    d_b = rng.integers(0, 6, (B, R)).astype(np.float32)
+    gone = rng.random((B, R)) < 0.3             # seen or absent neighbours
+    ids_b[gone], d_b[gone] = -1, INF
+    args = [jnp.asarray(x) for x in (ids_a, d_a, exp_a, ids_b, d_b)]
+    got = jax.vmap(search._merge_top_l)(*args)
+    want = jax.vmap(_merge_top_l_argsort)(*args)
+    for g, w in zip(got, want):
+        _assert_bits_equal(g, w)
+
+
+@pytest.mark.parametrize("instrument", [False, True])
+@pytest.mark.parametrize("R", [32, 38])
+def test_batched_search_matches_argsort_merge(monkeypatch, R, instrument):
+    """A search walks exactly as it did with the argsort merge: the same
+    ids, distances, hops and distance evaluations, on whole-number vectors
+    whose distances tie often and a graph with ``-1`` padding."""
+    rng = np.random.default_rng(R)
+    n, d, B = 600, 8, 32
+    db = jnp.asarray(rng.integers(0, 4, (n, d)).astype(np.float32))
+    nbrs = rng.integers(0, n, (n, R)).astype(np.int32)
+    nbrs[rng.random((n, R)) < 0.2] = -1
+    nbrs = jnp.asarray(nbrs)
+    queries = jnp.asarray(rng.integers(0, 4, (B, d)).astype(np.float32))
+    entries = jnp.asarray(rng.integers(0, n, (B, 2)).astype(np.int32))
+    params = SearchParams(k=10, beam_width=64, max_hops=96,
+                          instrument=instrument)
+    # jit keeps a trace per function and signature across jit objects:
+    # clear it, so each merge is traced, and none is left for other tests
+    jax.clear_caches()
+    got = batched_search(db, nbrs, queries, entries, params=params)
+    with monkeypatch.context() as m:
+        m.setattr(search, "_merge_top_l", _merge_top_l_argsort)
+        jax.clear_caches()
+        want = batched_search(db, nbrs, queries, entries, params=params)
+    jax.clear_caches()
+    if instrument:
+        (got, tele), (want, tele_w) = got, want
+        for g, w in zip(jax.tree.leaves(tele), jax.tree.leaves(tele_w)):
+            _assert_bits_equal(g, w)
+    for g, w in zip(got, want):
+        _assert_bits_equal(g, w)
+    assert int(np.asarray(got.hops).max()) > 1

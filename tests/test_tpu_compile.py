@@ -7,6 +7,8 @@ of seconds each.  Nothing is executed.  The topology is described inside a
 fixture — never at import time — because only one process may load the TPU
 library and every test worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -168,6 +170,22 @@ def test_batched_search_loop_has_no_scatter(one_chip, beam):
         *_search_args(one_chip, 100_000, 128, b=2048), params=params
     ).compile()
     assert " scatter(" not in compiled.as_text()
+
+
+def test_batched_search_merge_has_no_element_gather(one_chip, on_tpu):
+    """The served program (B = 1,024, beam 128, R = 38) merges each hop with
+    one sort that carries its operands.  Applying an argsort instead puts
+    one-element gathers over all B·(L+R) merge slots in the loop, which cost
+    the hop loop most of its time on a v5e."""
+    b, beam, r = 1024, 128, 38
+    params = SearchParams(k=10, beam_width=beam, max_hops=4 * beam,
+                          instrument=True, kernel="xla")
+    text = _batched_search.lower(
+        *_search_args(one_chip, 1_000_000, 128, b=b, r=r), params=params
+    ).compile().as_text()
+    shape = rf"\[({b},{beam + r}|{b * (beam + r)})\]"
+    assert re.search(r"\) sort\(", text)
+    assert not re.search(rf"= \w+{shape}\S* gather\(", text)
 
 
 def test_nsg_prune_has_no_scatter(one_chip):
