@@ -9,9 +9,13 @@ Every answer served in the window is compared with the plain reference
   corpus, or whose distances are not in ascending order.  Limit 0.
 * ``dist_gap``: the widest gap between a distance the server returned and
   the reference's float32 distance of the same row to the same query, over
-  every slot served, in units of that query's exact 10th-nearest distance.
-  Its limit is set from the readings in ``PERF.md`` and kept in the
-  configuration's file (``dist_gap_max``).
+  every slot served, in a unit of that query's own.  Under ``l2`` and
+  ``cosine`` the unit is the query's exact k-th nearest distance.  Under
+  ``ip`` the distance is ``-q.x``, which is mostly negative and crosses 0,
+  so the unit is ``|q| * |x_k|``, with ``x_k`` the reference's exact k-th
+  row: that bounds ``|q.x|`` near the cut-off, and float32 rounding of a
+  dot product scales with it.  Its limit is set from the readings in
+  ``PERF.md`` and kept in the configuration's file (``dist_gap_max``).
 * ``recall_at_10``: the share of the exact 10 nearest rows that the answers
   hold, over every query served; at least the configuration's stated
   ``recall_at_10_min``.
@@ -44,13 +48,29 @@ def bad_rows(ids: np.ndarray, dists: np.ndarray, n: int, k: int) -> int:
     return int(np.sum(out_of_range | repeated | unordered))
 
 
+def gap_unit(metric: str, queries: np.ndarray, kth_dists: np.ndarray,
+             kth_norms: np.ndarray) -> np.ndarray:
+    """Each query's unit of ``dist_gap``: its exact k-th distance
+    (``kth_dists``), or under ``ip`` its norm times the norm of its exact
+    k-th row (``kth_norms``)."""
+    if metric in ("l2", "cosine"):
+        unit = kth_dists
+    elif metric == "ip":
+        unit = np.linalg.norm(queries, axis=1) * kth_norms
+    else:
+        raise ValueError(f"metric {metric!r} has no unit of dist_gap")
+    return np.maximum(unit, 1e-12)
+
+
 def compare(queries: np.ndarray, ids: np.ndarray, dists: np.ndarray,
             true_ids: np.ndarray, true_dists: np.ndarray,
-            ref_dists_of_ids: np.ndarray, n: int, k: int) -> Dict[str, float]:
-    """The numbers compared, from the served answers and the reference's."""
+            ref_dists_of_ids: np.ndarray, n: int, k: int, metric: str,
+            kth_norms: np.ndarray) -> Dict[str, float]:
+    """The numbers compared, from the served answers and the reference's;
+    ``kth_norms`` are the norms of each query's exact k-th row."""
     if len(ids) == 0:
         return {"bad_rows": 0, "dist_gap": float("inf"), "recall_at_10": 0.0}
-    scale = np.maximum(true_dists[:, k - 1], 1e-12)
+    scale = gap_unit(metric, queries, true_dists[:, k - 1], kth_norms)
     valid = (ids >= 0) & (ids < n)
     gap = np.where(valid, np.abs(dists - ref_dists_of_ids), 0.0)
     dist_gap = float(np.max(gap / scale[:, None]))

@@ -22,7 +22,6 @@ import check
 import index_cache
 import spec
 import traffic as traffic_mod
-from data.synthetic import QueryMaker
 
 WARM_REQUESTS = 2
 
@@ -118,7 +117,9 @@ class Session:
             kernel=self.config["kernel"])
         index.warmup_ladder((rung,), batch_size=self.qpr, params=self.params)
         self.daemon.start(warmup=False)
-        self.maker = QueryMaker(self.db, self.mix["query_kind"])
+        gen = spec.generator(self.config["generator"], root)
+        self.maker = gen.query_maker(self.db, self.mix["query_kind"],
+                                     self.config)
 
     def submit(self, queries: np.ndarray):
         from repro.serve.daemon import SearchRequest
@@ -191,7 +192,8 @@ def judge_window(win: traffic_mod.Window, ref, config: dict,
     true_ids, true_d = ref.topk(queries, k)
     ref_d = ref.distances(queries, np.where(ids >= 0, ids, 0))
     numbers = check.compare(queries, ids, dists, true_ids, true_d, ref_d,
-                            ref.n, k)
+                            ref.n, k, config["metric"],
+                            ref.row_norms(true_ids[:, k - 1]))
     numbers["unanswered"] = sum(1 for r in win.records if not r.answered)
     correct, rows = check.judge(numbers, config)
     return {"numbers": numbers, "correct": correct, "rows": rows}

@@ -1,9 +1,10 @@
 """Built indexes, kept in the checkout so that only a cell's first run builds.
 
 An entry is ``GateIndex.save``'s file at ``bench/.cache/<config>-<key>.pkl``.
-The key hashes the configuration's build fields, the benchmark's data
-generator and every ``.py`` file under ``src/repro/``, so any change to the
-program or to the corpus builds again.  Every run serves an index loaded
+The key hashes the configuration's build fields, the configuration's own
+corpus generator (``bench/data/<generator>.py``) with the loader that finds
+it, and every ``.py`` file under ``src/repro/``, so any change to the program
+or to the corpus builds again.  Every run serves an index loaded
 from the cache, the run that built it too, so that all runs serve the same
 kind of object.
 """
@@ -16,9 +17,9 @@ import os
 import sys
 import time
 
-from spec import BENCH_DIR, ROOT
+import spec
+from spec import ROOT
 
-GENERATOR = os.path.join(BENCH_DIR, "data", "synthetic.py")
 BUILD_FIELDS = ("generator", "n", "d", "metric", "normalize", "corpus_seed",
                 "nsg", "gate", "train_queries", "train_query_kind")
 BUILD_STAGES = ("nsg.knn", "nsg.search_prune", "nsg.reverse_edges",
@@ -36,9 +37,10 @@ def cache_key(config: dict, root: str = ROOT) -> str:
     h = hashlib.sha256()
     build = {f: config.get(f) for f in BUILD_FIELDS}
     h.update(json.dumps(build, sort_keys=True).encode())
-    for path in [GENERATOR] + source_files(root):
-        name = (os.path.basename(path) if path == GENERATOR
-                else os.path.relpath(path, root))
+    files = [("bench/spec.py", spec.__file__),
+             ("generator", spec.generator_path(config["generator"], root))]
+    files += [(os.path.relpath(p, root), p) for p in source_files(root)]
+    for name, path in files:
         h.update(name.encode())
         with open(path, "rb") as f:
             h.update(hashlib.sha256(f.read()).digest())
@@ -49,18 +51,29 @@ def log(msg: str) -> None:
     print(f"bench: {msg}", file=sys.stderr, flush=True)
 
 
-def build_index(config: dict):
+def make_corpus(config: dict, root: str = ROOT):
+    """``(generator, db)``: the configuration's generator and its corpus,
+    refused where the corpus' width is not the stated ``d``."""
+    gen = spec.generator(config["generator"], root)
+    db = gen.make_corpus(config)
+    if db.ndim != 2 or db.shape[1] != config["d"]:
+        raise ValueError(
+            f"generator {config['generator']!r} made a corpus of shape "
+            f"{db.shape}; configuration {config['name']!r} states d="
+            f"{config['d']}")
+    return gen, db
+
+
+def build_index(config: dict, root: str = ROOT):
     """Corpus and training queries from the configuration, then
     ``GateIndex.build`` with its NSG and GATE settings."""
     import numpy as np
     from repro import obs
     from repro.core import GateConfig, GateIndex
 
-    from data.synthetic import QueryMaker, make_corpus
-
     t0 = time.perf_counter()
-    db = make_corpus(config)
-    tq = QueryMaker(db, config["train_query_kind"]).make(
+    gen, db = make_corpus(config, root)
+    tq = gen.query_maker(db, config["train_query_kind"], config).make(
         np.random.default_rng([config["corpus_seed"], 1]),
         config["train_queries"])
     log(f"corpus n={len(db)} d={db.shape[1]} ({time.perf_counter() - t0:.2f}"
@@ -93,7 +106,7 @@ def load_or_build(config: dict, root: str = ROOT):
     if not os.path.exists(path):
         log(f"index cache miss: building {config['name']}")
         t0 = time.perf_counter()
-        index = build_index(config)
+        index = build_index(config, root)
         os.makedirs(cache_dir, exist_ok=True)
         for old in glob.glob(os.path.join(cache_dir,
                                           f"{config['name']}-*.pkl")):

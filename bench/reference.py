@@ -3,10 +3,16 @@
 Independent of the program: it imports nothing from ``repro`` and reads only
 the corpus rows.  For each block of queries it scores every row in chunks at
 ``precision=HIGHEST`` (float32 on the TPU's matrix unit), keeps a shortlist
-of ``k + SLACK`` per chunk, and then scores the shortlist again as
-``sum((x - q)**2)`` (L2) or ``1 - x.q`` over unit rows (cosine), elementwise
+of ``k + SLACK`` per chunk, and then scores the shortlist again elementwise
 in float32, so that the final order does not rest on the matrix form's
-cancellation error.
+cancellation error.  Three metrics, each a distance in ascending order, the
+order in which the server returns its answers:
+
+* ``l2``: ``sum((x - q)**2)``;
+* ``cosine``: ``1 - x.q`` over unit rows and a unit query;
+* ``ip``: ``-sum(x * q)``, the negated inner product.
+
+Any other metric is refused, never scored as one of these.
 
 ``control_topk`` is the same search with its distances computed in bfloat16,
 the next precision below the configuration's float32: put in the program's
@@ -23,6 +29,7 @@ import numpy as np
 SLACK = 22          # shortlist per chunk beyond k
 CHUNK = 65_536      # corpus rows per scoring step
 Q_BLOCK = 512       # queries per call
+METRICS = ("l2", "cosine", "ip")
 
 
 def _prep_db(db: np.ndarray, metric: str, chunk: int):
@@ -47,7 +54,7 @@ def _shortlist(x, sq, q, *, n, m, chunk, metric):
     def one(c):
         xc = jax.lax.dynamic_slice_in_dim(x, c * chunk, chunk)
         s = jnp.matmul(q, xc.T, precision=jax.lax.Precision.HIGHEST)
-        if metric == "cosine":
+        if metric in ("cosine", "ip"):
             d = -s
         else:
             d = jax.lax.dynamic_slice_in_dim(sq, c * chunk, chunk)[None] - 2 * s
@@ -66,6 +73,8 @@ def _exact(x, q, ids, metric):
     if metric == "cosine":
         qn = q / jnp.maximum(jnp.linalg.norm(q, axis=1, keepdims=True), 1e-12)
         return 1.0 - jnp.sum(v * qn[:, None, :], axis=-1)
+    if metric == "ip":
+        return -jnp.sum(v * q[:, None, :], axis=-1)
     return jnp.sum((v - q[:, None, :]) ** 2, axis=-1)
 
 
@@ -81,10 +90,17 @@ def _distances(x, q, ids, *, metric):
     return _exact(x, q, jnp.maximum(ids, 0), metric)
 
 
+@jax.jit
+def _norms(x, ids):
+    return jnp.linalg.norm(x[ids], axis=-1)
+
+
 class Reference:
     """Exact top-k and exact distances over one corpus, on the device."""
 
     def __init__(self, db: np.ndarray, metric: str, chunk: int = CHUNK):
+        if metric not in METRICS:
+            raise ValueError(f"metric {metric!r} is not one of {METRICS}")
         self.metric = metric
         self.chunk = min(chunk, -(-len(db) // 8) * 8)
         self.x, self.sq, self.n = _prep_db(db, metric, self.chunk)
@@ -123,6 +139,19 @@ class Reference:
                 metric=self.metric))[:m]
         return out
 
+    def row_norms(self, ids: np.ndarray) -> np.ndarray:
+        """Float32 norms of rows ``ids`` (Q,) as the reference holds them
+        (unit rows under ``cosine``)."""
+        ids = np.asarray(ids)
+        out = np.empty(len(ids), np.float32)
+        for s in range(0, len(ids), Q_BLOCK):
+            blk = ids[s:s + Q_BLOCK]
+            m = len(blk)
+            blk = np.concatenate([blk, np.zeros(Q_BLOCK - m, blk.dtype)])
+            out[s:s + m] = np.asarray(_norms(
+                self.x, jnp.asarray(blk, jnp.int32)))[:m]
+        return out
+
 
 @functools.partial(jax.jit, static_argnames=("n", "k", "chunk", "metric"))
 def _control(x, sq, q, *, n, k, chunk, metric):
@@ -138,6 +167,8 @@ def _control(x, sq, q, *, n, k, chunk, metric):
         s = jnp.matmul(qb, xc.T).astype(jnp.float32)
         if metric == "cosine":
             d = 1.0 - s
+        elif metric == "ip":
+            d = -s
         else:
             xx = jnp.sum(xc.astype(jnp.float32) ** 2, axis=1)
             d = qq[:, None] + xx[None] - 2 * s

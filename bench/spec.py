@@ -2,12 +2,15 @@
 
 ``BENCHMARK.json`` at the root names the cells; a cell names a configuration
 (``bench/configs/<config>.json``, through the entry in ``configs``) and a
-traffic mix (``bench/traffic/<traffic>.json``); a per-layer metric is the
-reader ``bench/metrics/<metric>.py``.  Adding any of them means adding files,
-never editing code.
+traffic mix (``bench/traffic/<traffic>.json``); a configuration names its
+corpus generator (``bench/data/<generator>.py``), which holds the query kinds
+that a mix or the training queries name; a per-layer metric is the reader
+``bench/metrics/<metric>.py``.  Adding any of them means adding files, never
+editing code.
 """
 from __future__ import annotations
 
+import glob
 import importlib.util
 import json
 import os
@@ -73,15 +76,36 @@ def load_peaks(device_kind: str, root: str = ROOT) -> dict:
     return table["devices"][device_kind]
 
 
+def _load_module(path: str, prefix: str, name: str):
+    spec = importlib.util.spec_from_file_location(
+        prefix + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def generator(name: str, root: str = ROOT):
+    """The corpus generator ``bench/data/<name>.py``: its
+    ``make_corpus(config)`` gives the (n, d) float32 rows, and
+    ``query_maker(db, kind, config).make(rng, n_q)`` draws ``n_q`` queries
+    of one of its kinds (an unknown kind raises ``ValueError``)."""
+    path = generator_path(name, root)
+    if not os.path.exists(path):
+        have = sorted(os.path.basename(f)[:-3] for f in glob.glob(
+            os.path.join(os.path.dirname(path), "*.py")))
+        raise KeyError(f"no generator {name!r} in bench/data (have {have})")
+    return _load_module(path, "bench_data_", name)
+
+
+def generator_path(name: str, root: str = ROOT) -> str:
+    return os.path.join(root, "bench", "data", name + ".py")
+
+
 def metric_reader(name: str, root: str = ROOT
                   ) -> Callable[[dict], Optional[float]]:
     """``read(ctx)`` from ``bench/metrics/<name>.py``."""
     path = os.path.join(root, "bench", "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(path, "bench_metric_", name).read
 
 
 def read_metrics(entries: List[dict], ctx: dict, root: str = ROOT

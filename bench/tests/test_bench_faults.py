@@ -2,6 +2,7 @@
 comes out correct; with the timed path broken underneath, or with the
 bfloat16 reference in the program's place (the control), it does not."""
 import os
+import shutil
 import sys
 import time
 
@@ -92,7 +93,10 @@ def control(index):
 
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
+    """A checkout with the benchmark's generators, for the index cache."""
     r = str(tmp_path_factory.mktemp("bench_root"))
+    shutil.copytree(os.path.join(spec.BENCH_DIR, "data"),
+                    os.path.join(r, "bench", "data"))
     return r
 
 

@@ -1,5 +1,7 @@
 """Discovery by name: a configuration, a traffic mix and a per-layer metric
-dropped in as files, with no code edit; the peak table; the cache key."""
+dropped in as files, with no code edit (generators and their query kinds:
+``test_bench_data.py``); the peak table; the cache key, which follows the
+configuration's own generator."""
 import json
 import os
 import shutil
@@ -78,6 +80,9 @@ def test_cache_key_follows_the_program_and_the_build(tmp_path):
     r = str(tmp_path)
     src = os.path.join(r, "src", "repro", "graphs", "search.py")
     write(src, "x = 1\n")
+    gen = os.path.join(r, "bench", "data", "sift10m-like.py")
+    write(gen, "K = 1\n")
+    write(os.path.join(r, "bench", "data", "other-gen.py"), "K = 1\n")
     config = {"name": "c", "n": 100, "generator": "sift10m-like",
               "recall_at_10_min": 0.5}
     key = index_cache.cache_key(config, r)
@@ -85,6 +90,13 @@ def test_cache_key_follows_the_program_and_the_build(tmp_path):
     # a limit of the check is not part of the build
     assert index_cache.cache_key({**config, "recall_at_10_min": 0.4}, r) == key
     assert index_cache.cache_key({**config, "n": 101}, r) != key
+    # the configuration's own generator, by name and by its file
+    assert index_cache.cache_key({**config, "generator": "other-gen"},
+                                 r) != key
+    write(gen, "K = 2\n")
+    assert index_cache.cache_key(config, r) != key
+    write(gen, "K = 1\n")
+    assert index_cache.cache_key(config, r) == key
     write(src, "x = 2\n")
     changed = index_cache.cache_key(config, r)
     assert changed != key

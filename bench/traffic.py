@@ -14,8 +14,9 @@ A mix (``bench/traffic/<mix>.json``) is either
 * ``"loop": "closed"``: ``clients`` callers each send a request and wait for
   its reply before sending the next, until the window closes.
 
-The queries of every request come from the run's seed: which base rows they
-perturb and their noise (``query_kind``: ``in_dist``).
+The queries of every request come from the run's seed, drawn by the
+configuration's corpus generator as the mix's ``query_kind``
+(``bench/data/<generator>.py``).
 """
 from __future__ import annotations
 
