@@ -60,6 +60,7 @@ def make_search_step(
 
     Fixed-hop beam search per shard (bounded loop → static HLO), one
     all_gather merge. jit/lower-able with ShapeDtypeStructs for the dry-run.
+    Its kernel, ``beam_search_fixed``, scores squared L2 alone.
     """
     axes = _shard_axes(mesh)
     # ring only needs to hold every node this search can expand — sizing it

@@ -12,6 +12,11 @@ Search (online, fully jit-able):
   query tower MLP → greedy cosine descent on the nav graph → entry hub →
   Algorithm-1 beam search on the base graph.
 
+Inner product (``GateConfig(metric="ip")``): the build reduces maximum
+inner-product search to L2 (Bachrach et al., RecSys 2014) and builds the
+graph, hubs and samples on the reduced rows; the index keeps and serves the
+original rows under ``SearchParams(metric="ip")``.
+
 GATE is a *plug-in*: ``GateIndex.from_graph`` accepts any (neighbors, enter)
 pair, leaving the underlying index untouched (paper §1).
 """
@@ -99,6 +104,26 @@ def gate_select_entries(tower_params, queries, nav_reps, nav_neighbors,
     return hub_ids[hub_local], nav_hops
 
 
+# the search metrics an index built under each GateConfig.metric serves
+SERVES = {"l2": ("l2", "cosine"), "ip": ("ip",)}
+
+
+def mips_reduce(db: np.ndarray, queries: np.ndarray):
+    """``(rows, queries, M)`` one column wider, in which the L2 order is the
+    inner-product order (Bachrach et al., RecSys 2014): each row gains
+    ``sqrt(M² − ‖x‖²)`` with ``M = max ‖x‖``, each query 0, so that
+    ``‖q' − x'‖² = ‖q‖² + M² − 2 q·x``."""
+    sq = np.einsum("ij,ij->i", db, db)
+    m2 = sq.max()
+    rows = np.concatenate(
+        [db, np.sqrt(np.maximum(m2 - sq, 0.0))[:, None]], axis=1
+    ).astype(np.float32)
+    qs = np.concatenate(
+        [queries, np.zeros((len(queries), 1), queries.dtype)], axis=1
+    ).astype(np.float32)
+    return rows, qs, float(np.sqrt(m2))
+
+
 @dataclass(frozen=True)
 class GateConfig:
     n_hubs: int = 64            # |V| (paper: 512 at 10M scale)
@@ -130,6 +155,27 @@ class GateConfig:
     use_fusion: bool = True      # False → GATE w/o FE
     use_contrastive: bool = True # False → GATE w/o L (untrained towers)
     seed: int = 0
+    # the metric the index is built for: "l2" (serves l2 and cosine) or
+    # "ip" (serves ip alone; the build runs on mips_reduce's rows)
+    metric: str = "l2"
+
+    def __post_init__(self):
+        if self.metric not in SERVES:
+            raise ValueError(f"GateConfig.metric must be one of "
+                             f"{tuple(SERVES)}, got {self.metric!r}")
+
+
+def _build_space(db: np.ndarray, train_queries: np.ndarray,
+                 gcfg: GateConfig):
+    """The rows and training queries the graph, hubs and samples are built
+    on: under ``metric="ip"`` ``mips_reduce``'s, else the inputs."""
+    if gcfg.metric != "ip":
+        return db, train_queries
+    with span("gate.build.mips_reduce", n=len(db), d=db.shape[1]) as ev:
+        rows, qs, m = mips_reduce(db, train_queries)
+        if ev is not None:
+            ev["M"] = m
+    return rows, qs
 
 
 @dataclass
@@ -160,24 +206,36 @@ class GateIndex:
         train_queries: np.ndarray,
         gcfg: GateConfig = GateConfig(),
     ) -> "GateIndex":
+        """GATE over a given graph; under ``gcfg.metric="ip"`` the graph is
+        one built on ``mips_reduce``'s rows."""
+        return cls._from_graph(db, neighbors, enter_id, train_queries, gcfg,
+                               _build_space(db, train_queries, gcfg))
+
+    @classmethod
+    def _from_graph(cls, db, neighbors, enter_id, train_queries, gcfg,
+                    space) -> "GateIndex":
+        """``from_graph`` with the build space (rows, training queries)
+        already made: hubs, subgraphs and samples are built in it, the
+        towers and the index on ``db`` and ``train_queries``."""
+        space_db, space_tq = space
         report = {}
         t0 = time.time()
         with span("gate.build.hubs", n_hubs=gcfg.n_hubs,
                   method="hbkm" if gcfg.use_hbkm else "kmeans"):
             if gcfg.use_hbkm:
                 hubs = extract_hubs(
-                    db, gcfg.n_hubs, branch_k=gcfg.hbkm_branch,
+                    space_db, gcfg.n_hubs, branch_k=gcfg.hbkm_branch,
                     lam=gcfg.hbkm_lam, seed=gcfg.seed,
                 )
             else:
-                hubs = kmeans_hubs(db, gcfg.n_hubs, seed=gcfg.seed)
+                hubs = kmeans_hubs(space_db, gcfg.n_hubs, seed=gcfg.seed)
         report["t_hubs"] = time.time() - t0
 
         t0 = time.time()
         with span("gate.build.subgraphs", h=gcfg.h,
                   max_nodes=gcfg.subgraph_max_nodes):
             sgs = sample_all_subgraphs(
-                db, neighbors, hubs.ids, h=gcfg.h,
+                space_db, neighbors, hubs.ids, h=gcfg.h,
                 max_nodes=gcfg.subgraph_max_nodes, seed=gcfg.seed,
             )
         with span("gate.build.topo_embed", d_u=gcfg.d_u,
@@ -193,12 +251,12 @@ class GateIndex:
         t0 = time.time()
         with span("gate.build.samples", hop_mode=gcfg.hop_mode,
                   n_queries=len(train_queries)):
-            targets = top1_targets(db, train_queries)
+            targets = top1_targets(space_db, space_tq)
             if gcfg.hop_mode == "greedy":
                 from repro.core.samples import greedy_hops
 
                 hops = greedy_hops(
-                    db, neighbors, train_queries, hubs.ids, targets,
+                    space_db, neighbors, space_tq, hubs.ids, targets,
                     beam_width=gcfg.hop_beam, max_hops=gcfg.hop_max,
                 )
             else:
@@ -251,11 +309,15 @@ class GateIndex:
         nsg: Optional[NSG] = None,
         **nsg_kw,
     ) -> "GateIndex":
+        """NSG, then GATE over it.  Under ``gcfg.metric="ip"`` everything
+        built with L2 (the graph, hubs, samples) is built on
+        ``mips_reduce``'s rows, which are freed once it is built."""
+        space = _build_space(db, train_queries, gcfg)
         if nsg is None:
             with span("gate.build.nsg", n=len(db)):
-                nsg = build_nsg(db, **nsg_kw)
-        return cls.from_graph(
-            db, nsg.neighbors, nsg.enter_id, train_queries, gcfg
+                nsg = build_nsg(space[0], **nsg_kw)
+        return cls._from_graph(
+            db, nsg.neighbors, nsg.enter_id, train_queries, gcfg, space
         )
 
     # ----------------------------------------------------------------- search
@@ -335,6 +397,11 @@ class GateIndex:
                     *(jnp.asarray(a) for a in q)
                 )
             kw["quant"] = dev["quant"]
+        if params.metric == "ip" and params.instrument:
+            if "mips_m2" not in dev:
+                db32 = dev["db"].astype(jnp.float32)
+                dev["mips_m2"] = jnp.max(jnp.sum(db32 * db32, axis=-1))
+            kw["mips_m2"] = dev["mips_m2"]
         if (params.kernel == "fused" and not params.kernel_interpret
                 and dev["db"].shape[1] != 128):
             from repro.kernels.gather_dist import lane_rows
@@ -345,6 +412,17 @@ class GateIndex:
                     dev["db_lane"] = lane_rows(dev["db"])
                 kw["db_lane"] = dev["db_lane"]
         return kw
+
+    def check_metric(self, params: SearchParams, where: str) -> None:
+        """Refuse a search metric this index was not built for: an ``ip``
+        index serves ``ip`` alone, an ``l2`` index ``l2`` and ``cosine``."""
+        built = self.gcfg.metric
+        if params.metric not in SERVES[built]:
+            raise ValueError(
+                f"{where}: an index built under metric={built!r} serves "
+                f"{SERVES[built]}, not a search under metric="
+                f"{params.metric!r}"
+            )
 
     def _search_args(self, queries, entries, params: SearchParams):
         """``(args, kwargs)`` of the ``batched_search`` call ``search``
@@ -357,6 +435,7 @@ class GateIndex:
     def lower_search(self, queries: np.ndarray, *, params: SearchParams):
         """The program ``search`` runs for these queries and params,
         lowered (``jax.stages.Lowered``) and not run."""
+        self.check_metric(params, "GateIndex.lower_search")
         args, kw = self._search_args(
             queries, self.select_entries(queries), params
         )
@@ -466,6 +545,7 @@ class GateIndex:
             "GateIndex.warmup_ladder", params, legacy,
             default=SearchParams(instrument=True),
         )
+        self.check_metric(base, "GateIndex.warmup_ladder")
         d = self.db.shape[1]
         dummy = np.zeros((batch_size, d), self.db.dtype)
         with span("gate.warmup_ladder", rungs=len(ladder),
@@ -545,6 +625,7 @@ class GateIndex:
                 )
             telemetry_sink = _UNSET if record else None
         params = resolve_search_params("GateIndex.search", params, legacy, k=k)
+        self.check_metric(params, "GateIndex.search")
         sink = registry_sink if telemetry_sink is _UNSET else telemetry_sink
         with span("gate.select_entries"):
             entries, nav_hops = self.select_entries(queries, instrument=True)
@@ -561,8 +642,15 @@ class GateIndex:
             # device anyway; the wait gets a span of its own
             with span("gate.search.device_wait"):
                 jax.block_until_ready((res, tele))
-            with span("gate.search.telemetry"):
+            with span("gate.search.telemetry") as ev:
                 sink(tele, params=params, where="GateIndex.search")
+                if ev is not None:
+                    # free where the sink read the field to the host (the
+                    # registry's sink does while the registry is enabled):
+                    # the array keeps that copy; else this is the one read
+                    conv = np.asarray(tele.converged_hop)
+                    ev["converged_hop_sum"] = int(conv.sum())
+                    ev["queries"] = int(conv.size)
         return res, tele
 
     def search_routed(
@@ -595,6 +683,7 @@ class GateIndex:
         base = resolve_search_params(
             "GateIndex.search_routed", params, {}, k=k
         )
+        self.check_metric(base, "GateIndex.search_routed")
         sink = registry_sink if telemetry_sink is _UNSET else telemetry_sink
         dev = self._device()
         qd = jnp.asarray(queries)
@@ -696,6 +785,7 @@ class GateIndex:
         params = resolve_search_params(
             "GateIndex.search_baseline", params, legacy, k=k
         )
+        self.check_metric(params, "GateIndex.search_baseline")
         dev = self._device()
         B = len(queries)
         if entry == "medoid":

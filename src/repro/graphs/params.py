@@ -24,7 +24,7 @@ import warnings
 from dataclasses import dataclass
 from typing import Dict, Optional, Set, Tuple
 
-_METRICS = ("l2", "cosine")
+_METRICS = ("l2", "cosine", "ip")
 
 # Distance-kernel variants (ISSUE 10).  "xla" is the plain gather+compute
 # formulation; "fused" DMAs rows in-kernel via scalar prefetch (bit-identical
@@ -57,7 +57,7 @@ class SearchParams:
     beam_width: int = 64        # Algorithm-1 beam slots L
     max_hops: int = 256         # expansion budget
     visited_ring: int = 512     # dedup ring capacity
-    metric: str = "l2"          # "l2" (squared) or "cosine" (1 - cos)
+    metric: str = "l2"          # "l2" (squared), "cosine" (1 - cos), "ip" (-q·x)
     instrument: bool = False    # device-side SearchTelemetry on/off
     conv_k: int = 10            # top-k prefix watched for convergence
     kernel: str = "xla"         # distance kernel: "xla" | "fused" | "fused_q8"
@@ -72,6 +72,11 @@ class SearchParams:
         if self.kernel not in _KERNELS:
             raise ValueError(
                 f"kernel must be one of {_KERNELS}, got {self.kernel!r}"
+            )
+        if self.metric == "ip" and self.kernel != "xla":
+            raise ValueError(
+                f'metric="ip" is served by kernel="xla" only; kernel='
+                f"{self.kernel!r} scores l2 and cosine"
             )
         for name in ("k", "beam_width", "max_hops", "visited_ring", "conv_k",
                      "rerank_mult"):

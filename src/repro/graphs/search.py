@@ -12,7 +12,8 @@ row (R,), mask already-seen ids (beam + visited ring), compute distances
 (the kernels/gather_dist hot spot), merge-and-keep top-L.  Terminates when
 every beam slot is expanded (the Algorithm-1 condition) or at max_hops.
 
-Distances are squared L2 (monotone-equivalent to L2).
+Distances are squared L2 (monotone-equivalent to L2), ``1 - cos`` or, for
+inner product, ``-q·x``: every metric ascends.
 
 Telemetry (``instrument=True``, a static arg): the loops additionally
 accumulate a ``SearchTelemetry`` pytree — visited-ring evictions (silent
@@ -154,10 +155,24 @@ def _make_dist_fns(
             vecs = db[jnp.maximum(ids, 0)].astype(jnp.float32)
             d = jnp.sum((vecs - qx) ** 2, axis=-1)
             return jnp.where(ids < 0, INF, d)
+    elif metric == "ip":
+        qx = qf
+        inv = None
+
+        # an elementwise product and sum in float32, as l2 scores diff²: a
+        # default-precision dot runs float32 in bfloat16 passes on the TPU
+        def exact_dist(ids):
+            vecs = db[jnp.maximum(ids, 0)].astype(jnp.float32)
+            d = -jnp.sum(vecs * qx, axis=-1)
+            return jnp.where(ids < 0, INF, d)
     else:
         raise ValueError(metric)
 
-    vec_bytes = D * db.dtype.itemsize
+    if metric == "ip" and kernel != "xla":
+        raise ValueError(f'metric="ip" is served by kernel="xla" only, not '
+                         f"kernel={kernel!r}")
+
+    vec_bytes = D * db.dtype.itemsize  # l2 and ip read the row alone
     if metric == "cosine":
         vec_bytes += 4  # the inv-norm read per scored row
 
@@ -234,11 +249,15 @@ def beam_search_single(
     inv_norms: Optional[jax.Array] = None,
     quant=None,
     db_lane: Optional[jax.Array] = None,
+    mips_m2: Optional[jax.Array] = None,
 ):
     """One query's Algorithm-1 beam search.
 
     ``metric="l2"`` ranks by squared L2; ``"cosine"`` by 1 − cos(v, q)
-    (monotone in angle; vectors need not be pre-normalized).
+    (monotone in angle; vectors need not be pre-normalized); ``"ip"`` by
+    −q·v (maximum inner product), on the ``xla`` kernel only.  ``mips_m2``
+    is ``M²``, the largest squared row norm, which the instrumented ``ip``
+    search needs for ``entry_rank_proxy``: it is refused without it.
 
     ``kernel`` selects the distance path (see docs/kernels.md): ``"xla"``
     gather+score, ``"fused"`` in-kernel gather via scalar prefetch
@@ -375,6 +394,22 @@ def beam_search_single(
         bytes_read = bytes_read + rr_valid.astype(jnp.float32) * float(
             exact_bytes
         )
+    if metric == "ip":
+        # -q·x is mostly negative, so its ratio means nothing.  The ratio of
+        # the two distances in the reduced L2 space the graph was built in
+        # (GateIndex.build), ‖q‖² + M² − 2 q·x, keeps the proxy's meaning:
+        # at least 1, and exactly 1 when the entry is the final top-1
+        if mips_m2 is None:
+            raise ValueError(
+                'an instrumented metric="ip" search needs mips_m2 (M², the '
+                "largest squared row norm) for entry_rank_proxy"
+            )
+        qf = q.astype(jnp.float32)
+        base = jnp.sum(qf * qf) + mips_m2
+        rank_proxy = (jnp.maximum(base + 2.0 * entry_dist, 1e-12)
+                      / jnp.maximum(base + 2.0 * beam_d[0], 1e-12))
+    else:
+        rank_proxy = entry_dist / jnp.maximum(beam_d[0], 1e-12)
     tele = SearchTelemetry(
         hops=hops,
         dist_evals=evals,
@@ -382,7 +417,7 @@ def beam_search_single(
         converged_hop=conv_hop,
         nav_hops=jnp.zeros((), jnp.int32),
         entry_dist=entry_dist,
-        entry_rank_proxy=entry_dist / jnp.maximum(beam_d[0], 1e-12),
+        entry_rank_proxy=rank_proxy,
         bytes_read=bytes_read,
     )
     return beam_ids, beam_d, hops, evals, tele
@@ -397,12 +432,14 @@ def _batched_search(
     inv_norms: Optional[jax.Array] = None,  # (N,) cosine 1/‖row‖ cache
     quant=None,                             # repro.quant.QuantizedDb pytree
     db_lane: Optional[jax.Array] = None,    # (N·k, 128) lane-row view of db
+    mips_m2: Optional[jax.Array] = None,    # () ip: largest squared row norm
     *,
     params: SearchParams,
 ):
     """Jitted core: one compiled program per (shapes, ``params``) pair —
     ``SearchParams`` is frozen/hashable, so it is the whole static key.
-    ``inv_norms``/``quant``/``db_lane`` are ordinary (pytree) operands:
+    ``inv_norms``/``quant``/``db_lane``/``mips_m2`` are ordinary (pytree)
+    operands:
     presence vs ``None`` changes the treedef and therefore the cache entry,
     so callers must pass them consistently per params (``GateIndex`` derives
     them from the params deterministically)."""
@@ -434,6 +471,7 @@ def _batched_search(
         inv_norms=inv_norms,
         quant=quant,
         db_lane=db_lane,
+        mips_m2=mips_m2,
     )
     if not params.instrument:
         beam_ids, beam_d, hops, evals = jax.vmap(fn)(queries, entry_ids)
@@ -453,6 +491,7 @@ def batched_search(
     inv_norms: Optional[jax.Array] = None,
     quant=None,
     db_lane: Optional[jax.Array] = None,
+    mips_m2: Optional[jax.Array] = None,
     **legacy,
 ):
     """Batched Algorithm-1 search.
@@ -467,7 +506,9 @@ def batched_search(
     ``metric="cosine"`` optionally ``inv_norms=`` to reuse a precomputed
     ``1/‖row‖`` cache across calls, and for ``"fused"`` on TPU with
     ``d != 128`` optionally ``db_lane=`` (``lane_rows(db)``) so the view
-    isn't re-materialized inside every search batch.
+    isn't re-materialized inside every search batch, and for an
+    instrumented ``metric="ip"`` ``mips_m2=`` (``M²``, the largest
+    squared row norm) for ``entry_rank_proxy``.
 
     ``params.instrument=False`` (default): returns ``SearchResult`` — the
     HLO is identical to the pre-telemetry program.  ``instrument=True``:
@@ -476,7 +517,7 @@ def batched_search(
     params = resolve_search_params("batched_search", params, legacy, k=k)
     return _batched_search(
         db, neighbors, queries, entry_ids, inv_norms, quant, db_lane,
-        params=params,
+        *_optional(mips_m2), params=params,
     )
 
 
@@ -490,6 +531,7 @@ def lower_batched_search(
     inv_norms: Optional[jax.Array] = None,
     quant=None,
     db_lane: Optional[jax.Array] = None,
+    mips_m2: Optional[jax.Array] = None,
 ):
     """``batched_search``'s program for these arguments, lowered and not
     run: the same jit entry and operands, so ``.compile().as_text()`` is the
@@ -497,8 +539,15 @@ def lower_batched_search(
     kernel)."""
     return _batched_search.lower(
         db, neighbors, queries, entry_ids, inv_norms, quant, db_lane,
-        params=params,
+        *_optional(mips_m2), params=params,
     )
+
+
+def _optional(mips_m2) -> tuple:
+    """``mips_m2`` as a trailing operand where it is given: a search
+    without it (every ``l2`` and ``cosine`` search) keeps the call, and the
+    compiled program's operands, it had before ``ip``."""
+    return () if mips_m2 is None else (mips_m2,)
 
 
 def search_jit_cache_size() -> int:

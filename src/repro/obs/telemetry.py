@@ -15,7 +15,9 @@ Field ↔ paper mapping (PAPER.md §5, arXiv:2402.04713, arXiv:2510.22316):
   entry_dist        best entry candidate's distance to the query
   entry_rank_proxy  entry_dist / final top-1 distance — 1.0 means the
                     chosen entry already was the answer; large values mean
-                    a poor entry (entry-quality proxy without ground truth)
+                    a poor entry (entry-quality proxy without ground truth);
+                    under ``ip`` the two distances are taken in the reduced
+                    L2 space the index was built in, ‖q‖² + M² − 2 q·x
   bytes_read        estimated HBM bytes this query's search read (vector
                     rows × bytes/row for the active kernel + neighbor-list
                     reads + the q8 rerank's exact rows) — the

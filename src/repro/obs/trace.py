@@ -156,10 +156,14 @@ def span(name: str, *, req: Optional[int] = None, **attrs):
     ``req`` tags the span and the spans opened inside it with a request id.
     Attribute values land in the trace event's ``args`` (and the profiler
     event's metadata) and must be JSON-serializable.
+
+    It yields the attribute dict while it records, and ``None`` otherwise:
+    attributes known only inside the span go into that dict, and reach the
+    trace event's ``args`` (not the profiler event, which began already).
     """
     t = _TRACER
     if not t.enabled and not _profiling():
-        yield
+        yield None
         return
     stack = t._stack()
     parent, parent_req = stack[-1] if stack else (None, None)
@@ -171,7 +175,7 @@ def span(name: str, *, req: Optional[int] = None, **attrs):
     start = time.perf_counter()
     try:
         with TraceAnnotation(name, **meta):
-            yield
+            yield attrs
     finally:
         end = time.perf_counter()
         stack.pop()

@@ -135,10 +135,12 @@ class ServeDaemon:
         # picks the distance path (ISSUE 10) daemon-wide: the ladder warmup
         # below compiles every rung against it, so per-request params that
         # keep the daemon's kernel never recompile.  fused_q8 quantizes the
-        # index on warmup (ensure_quantized) before traffic arrives.
+        # index on warmup (ensure_quantized) before traffic arrives.  The
+        # metric is the one the index was built for ("l2" serves l2).
         self.base_params = SearchParams(
             k=k, visited_ring=visited_ring, instrument=True,
             kernel=kernel, kernel_interpret=kernel_interpret,
+            metric=index.gcfg.metric,
         )
         if kernel == "fused_q8":
             index.ensure_quantized()

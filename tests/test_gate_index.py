@@ -179,3 +179,188 @@ def test_ablation_variants_build():
         idx = GateIndex.from_graph(db, nsg.neighbors, nsg.enter_id, tq, g)
         res = idx.search(eq, k=5, beam_width=16, max_hops=64)
         assert np.asarray(res.ids).shape == (32, 5)
+
+
+# ------------------------------------------------------------ inner product
+# SHA-256 of an l2 GateIndex.build at a fixed seed, taken on the code before
+# the build learned inner product: the l2 build may not move by a bit.
+L2_BUILD_PINS = {
+    "neighbors":
+        "4662a6ab361ee23174c519b77cdfcd9a9524733d7619875d7cc3640663edf6ab",
+    "hubs": "c23d08ae24be6f1096ac7487a9335ea4f9d51fb42c0566312c995808c31490df",
+    "enter_id": 228,
+    "entries":
+        "df1f67d9ab4baad184c6b651450ec59550f0ddd19d3446312ae3c70717251567",
+}
+
+
+def _sha(a) -> str:
+    import hashlib
+
+    return hashlib.sha256(np.ascontiguousarray(a).tobytes()).hexdigest()
+
+
+def test_l2_build_is_bit_identical_to_before_ip():
+    db, _ = make_database("sift10m-like", 1500, seed=5)
+    tq, eq = train_eval_query_split(db, 128, 64)
+    g = GateConfig(n_hubs=12, epochs=8, batch_hubs=12, subgraph_max_nodes=32)
+    idx = GateIndex.build(db, tq, g, R=12, knn_k=12, search_l=24,
+                          pool_size=32)
+    assert idx.gcfg.metric == "l2"
+    got = {"neighbors": _sha(idx.neighbors), "hubs": _sha(idx.hubs.ids),
+           "enter_id": int(idx.enter_id),
+           "entries": _sha(np.asarray(idx.select_entries(eq)))}
+    assert got == L2_BUILD_PINS
+
+
+def _ip_corpus(n, d=200, seed=0):
+    """Clustered rows whose norms differ, training and evaluation queries
+    drawn near rows and moved off them by a fixed offset."""
+    rng = np.random.default_rng(seed)
+    centers = rng.standard_normal((24, d))
+    centers /= np.linalg.norm(centers, axis=1, keepdims=True)
+    db = (centers[rng.integers(0, 24, n)]
+          + 0.04 * rng.standard_normal((n, d))) * rng.uniform(0.8, 1.2,
+                                                               (n, 1))
+    offset = 0.3 * rng.standard_normal(d) / np.sqrt(d)
+
+    def queries(m):
+        base = db[rng.integers(0, n, m)]
+        return base + offset + 0.03 * rng.standard_normal((m, d))
+
+    f32 = lambda a: a.astype(np.float32)
+    return f32(db), f32(queries(384)), f32(queries(128))
+
+
+def _ip_truth(db, q, k=10):
+    """The plain reference: float64 ``-q·x`` top-k."""
+    d = -(q.astype(np.float64) @ db.astype(np.float64).T)
+    return np.argsort(d, axis=1, kind="stable")[:, :k]
+
+
+@pytest.fixture(scope="module")
+def ip_index():
+    db, tq, eq = _ip_corpus(4000)
+    g = GateConfig(n_hubs=24, epochs=40, batch_hubs=24,
+                   subgraph_max_nodes=48, metric="ip")
+    idx = GateIndex.build(db, tq, g, R=24, knn_k=24, search_l=48,
+                          pool_size=64)
+    return idx, eq
+
+
+def _ip_params(**kw):
+    from repro.graphs.params import SearchParams
+
+    return SearchParams(**{"k": 10, "beam_width": 64, "max_hops": 256,
+                           "metric": "ip", **kw})
+
+
+def test_ip_build_reduces_and_keeps_the_rows(ip_index):
+    """The graph and hubs come from the reduced (d + 1)-wide rows; the index
+    keeps and serves the original rows, and its towers see d-wide input."""
+    idx, _ = ip_index
+    assert idx.db.shape == (4000, 200)
+    assert idx.hubs.centroids.shape[1] == 201
+    assert idx.tower_cfg.d_p == 200
+    np.testing.assert_array_equal(idx.db, _ip_corpus(4000)[0])
+
+
+def test_ip_index_search_reaches_recall(ip_index):
+    """GATE entries, then the ``ip`` walk: recall@10 against the float64
+    reference of at least 0.75 (it reads 0.835 at beam 64 on this corpus),
+    with every distance the reference's ``-q·x`` of its id."""
+    idx, eq = ip_index
+    res = idx.search(eq, params=_ip_params())
+    ids, dists = np.asarray(res.ids), np.asarray(res.dists)
+    assert recall_at_k(ids, _ip_truth(idx.db, eq), 10) >= 0.75
+    want = -np.einsum("qd,qkd->qk", eq.astype(np.float64),
+                      idx.db[ids].astype(np.float64))
+    np.testing.assert_allclose(dists, want, rtol=0, atol=1e-5)
+
+
+def test_ip_index_save_load_keeps_the_metric(ip_index, tmp_path):
+    idx, eq = ip_index
+    path = os.path.join(tmp_path, "ip.pkl")
+    idx.save(path)
+    idx2 = GateIndex.load(path)
+    assert idx2.gcfg.metric == "ip"
+    r1 = idx.search(eq[:16], params=_ip_params())
+    r2 = idx2.search(eq[:16], params=_ip_params())
+    np.testing.assert_array_equal(np.asarray(r1.ids), np.asarray(r2.ids))
+    with pytest.raises(ValueError, match=r"'ip'.*metric='l2'"):
+        idx2.search(eq[:16], k=10)
+
+
+@pytest.mark.parametrize("call", ["search", "search_baseline",
+                                  "warmup_ladder", "lower_search"])
+@pytest.mark.parametrize("metric", ["l2", "cosine"])
+def test_ip_index_refuses_other_metrics(ip_index, call, metric):
+    from repro.obs import LadderRung
+
+    idx, eq = ip_index
+    sp = _ip_params(metric=metric)
+    run = {
+        "search": lambda: idx.search(eq, params=sp),
+        "search_baseline": lambda: idx.search_baseline(eq, params=sp),
+        "warmup_ladder": lambda: idx.warmup_ladder(
+            (LadderRung(16, 32),), batch_size=4, params=sp),
+        "lower_search": lambda: idx.lower_search(eq, params=sp),
+    }[call]
+    with pytest.raises(ValueError,
+                       match=rf"built under metric='ip'.*metric='{metric}'"):
+        run()
+
+
+@pytest.mark.parametrize("call", ["search", "search_baseline"])
+def test_l2_index_refuses_ip(built_index, call):
+    idx, eq = built_index
+    with pytest.raises(ValueError,
+                       match=r"built under metric='l2'.*metric='ip'"):
+        getattr(idx, call)(eq, params=_ip_params())
+
+
+def test_gate_config_refuses_an_unknown_metric():
+    with pytest.raises(ValueError, match=r"\('l2', 'ip'\).*'cosine'"):
+        GateConfig(metric="cosine")
+
+
+def test_daemon_serves_an_ip_index_by_its_metric(ip_index):
+    """The daemon takes its metric from the index: warmed and asked with no
+    params, it serves the ``ip`` search."""
+    from repro.obs import LadderRung
+    from repro.serve.daemon import ServeDaemon
+
+    idx, eq = ip_index
+    rung = LadderRung(64, 256)
+    daemon = ServeDaemon(idx, ladder=(rung,), adaptive=False, level=0,
+                         batch_size=32, k=10)
+    assert daemon.base_params.metric == "ip"
+    daemon.start(warmup=True)
+    try:
+        res, _ = daemon.search(eq[:32])
+    finally:
+        daemon.stop()
+    want = idx.search(eq[:32], params=_ip_params())
+    np.testing.assert_array_equal(np.asarray(res.ids), np.asarray(want.ids))
+
+
+@pytest.mark.parametrize("fixture", ["built_index", "ip_index"])
+def test_telemetry_span_sums_converged_hops(request, fixture, monkeypatch):
+    """``gate.search.telemetry`` carries its batch's summed
+    ``converged_hop`` and its query count while the tracer records."""
+    import repro.obs.trace as trace_mod
+
+    idx, eq = request.getfixturevalue(fixture)
+    sp = _ip_params(metric=idx.gcfg.metric, instrument=True)
+    t = trace_mod.Tracer()
+    monkeypatch.setattr(trace_mod, "_TRACER", t)
+    t.start()
+    try:
+        _, tele = idx.search(eq, params=sp)
+    finally:
+        t.stop()
+    spans = [e for e in t.events() if e["name"] == "gate.search.telemetry"]
+    assert len(spans) == 1
+    assert spans[0]["args"] == {
+        "converged_hop_sum": int(np.asarray(tele.converged_hop).sum()),
+        "queries": len(eq)}

@@ -299,3 +299,134 @@ def test_batched_search_matches_argsort_merge(monkeypatch, R, instrument):
     for g, w in zip(got, want):
         _assert_bits_equal(g, w)
     assert int(np.asarray(got.hops).max()) > 1
+
+
+# ------------------------------------------------------------ inner product
+def _ip_data(n=2000, d=200, n_q=64, seed=0):
+    """Clustered rows whose norms differ, and queries near rows of theirs."""
+    rng = np.random.default_rng(seed)
+    centers = rng.standard_normal((32, d))
+    db = (centers[rng.integers(0, 32, n)] + 0.5 * rng.standard_normal((n, d))
+          ) * rng.uniform(0.8, 1.2, (n, 1))
+    q = db[rng.integers(0, n, n_q)] + 0.5 * rng.standard_normal((n_q, d))
+    return db.astype(np.float32), q.astype(np.float32)
+
+
+def _ip_topk(db, q, k):
+    """The plain reference: float64 ``-q·x``, ascending."""
+    d = -(q.astype(np.float64) @ db.astype(np.float64).T)
+    ids = np.argsort(d, axis=1, kind="stable")[:, :k]
+    return ids, np.take_along_axis(d, ids, axis=1), d
+
+
+def test_mips_reduce_l2_order_is_ip_order():
+    """Over the reduced rows the exact L2 top-k is the inner-product top-k,
+    id for id, on data whose scores have no ties."""
+    from repro.core.gate_index import mips_reduce
+
+    db, q = _ip_data()
+    rows, qs, m = mips_reduce(db, q)
+    assert rows.shape == (2000, 201) and qs.shape == (64, 201)
+    np.testing.assert_allclose(m, np.linalg.norm(db, axis=1).max(), rtol=1e-6)
+    np.testing.assert_array_equal(rows[:, :200], db)
+    np.testing.assert_array_equal(qs[:, 200], 0.0)
+    want, _, scores = _ip_topk(db, q, 11)
+    # the queries without ties among their first 11: float32 rounding of
+    # the reduced rows moves a distance by about M²·2⁻²³, so a gap of 100
+    # times that is no tie
+    gaps = np.diff(np.take_along_axis(scores, want, axis=1), axis=1)
+    keep = gaps.min(axis=1) > 100 * m * m * 2.0 ** -23
+    assert keep.sum() >= len(q) // 2
+    want, rows_q = want[keep, :10], qs[keep]
+    l2 = ((rows_q[:, None, :].astype(np.float64)
+           - rows[None].astype(np.float64)) ** 2).sum(-1)
+    np.testing.assert_array_equal(np.argsort(l2, axis=1)[:, :10], want)
+    got, _ = exact_knn(rows_q, rows, 10)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.fixture(scope="module")
+def ip_graph():
+    from repro.core.gate_index import mips_reduce
+
+    db, q = _ip_data(n=3000, seed=1)
+    rows, _, _ = mips_reduce(db, q)
+    nsg = build_nsg(rows, R=24, knn_k=24, search_l=48, pool_size=64)
+    return db, q, nsg
+
+
+def test_batched_search_ip_distances_match_reference(ip_graph):
+    """Under ``ip`` the served distances are the float64 reference's
+    ``-q·x`` of the same ids, ascending.  Tolerance: a float32 sum of
+    d = 200 products, each at most ‖q‖·‖x‖ in size, is off by at most
+    about d·2⁻²⁴·‖q‖·‖x‖ ≈ 1.2e-5·‖q‖·‖x‖."""
+    db, q, nsg = ip_graph
+    entries = jnp.full((len(q), 1), nsg.enter_id, jnp.int32)
+    res = batched_search(
+        jnp.asarray(db), jnp.asarray(nsg.neighbors), jnp.asarray(q), entries,
+        params=SearchParams(k=10, beam_width=96, max_hops=512, metric="ip"))
+    ids, dists = np.asarray(res.ids), np.asarray(res.dists)
+    assert (ids >= 0).all()
+    assert np.all(np.diff(dists, axis=1) >= 0)
+    want = -np.einsum("qd,qkd->qk", q.astype(np.float64),
+                      db[ids].astype(np.float64))
+    unit = (np.linalg.norm(q, axis=1)[:, None]
+            * np.linalg.norm(db[ids], axis=-1))
+    assert np.max(np.abs(dists - want) / unit) < 1.2e-5
+
+
+def test_ip_search_walks_as_l2_over_the_reduced_rows(ip_graph):
+    """The ``ip`` walk over the rows is the ``l2`` walk over the reduced
+    rows: the same ids and hops, since ``‖q' − x'‖² = ‖q‖² + M² − 2 q·x``
+    orders every candidate as ``-q·x`` does."""
+    from repro.core.gate_index import mips_reduce
+
+    db, q, nsg = ip_graph
+    rows, qs, _ = mips_reduce(db, q)
+    entries = jnp.full((len(q), 1), nsg.enter_id, jnp.int32)
+    params = SearchParams(k=10, beam_width=64, max_hops=256, metric="ip")
+    nbrs = jnp.asarray(nsg.neighbors)
+    got = batched_search(jnp.asarray(db), nbrs, jnp.asarray(q), entries,
+                         params=params)
+    want = batched_search(jnp.asarray(rows), nbrs, jnp.asarray(qs), entries,
+                          params=params.replace(metric="l2"))
+    np.testing.assert_array_equal(np.asarray(got.ids), np.asarray(want.ids))
+    np.testing.assert_array_equal(np.asarray(got.hops),
+                                  np.asarray(want.hops))
+
+
+def test_entry_rank_proxy_under_ip(ip_graph):
+    """``entry_rank_proxy`` under ``ip`` is at least 1, and exactly 1 when
+    the entry is the final top-1."""
+    db, q, nsg = ip_graph
+    true, _, _ = _ip_topk(db, q, 1)
+    params = SearchParams(k=10, beam_width=64, max_hops=256, metric="ip",
+                          instrument=True)
+    args = (jnp.asarray(db), jnp.asarray(nsg.neighbors), jnp.asarray(q))
+    m2 = jnp.max(jnp.sum(jnp.asarray(db) ** 2, axis=1))
+    res, tele = batched_search(*args, jnp.asarray(true, jnp.int32),
+                               params=params, mips_m2=m2)
+    np.testing.assert_array_equal(np.asarray(res.ids)[:, 0], true[:, 0])
+    np.testing.assert_array_equal(np.asarray(tele.entry_rank_proxy), 1.0)
+    far = jnp.full((len(q), 1), nsg.enter_id, jnp.int32)
+    res, tele = batched_search(*args, far, params=params, mips_m2=m2)
+    proxy = np.asarray(tele.entry_rank_proxy)
+    assert (proxy >= 1.0).all() and proxy.mean() > 1.0
+    # without the operand the instrumented ip search is refused
+    with pytest.raises(ValueError, match="mips_m2"):
+        batched_search(*args, far, params=params)
+
+
+@pytest.mark.parametrize("kernel", ["fused", "fused_q8"])
+def test_ip_is_refused_off_the_xla_kernel(kernel):
+    """The fused kernels score l2 and cosine: under ``ip`` they refuse by
+    name, at the params and at the distance function, and never fall back
+    to L2."""
+    with pytest.raises(ValueError, match=rf'metric="ip".*{kernel}'):
+        SearchParams(metric="ip", kernel=kernel)
+    db = jnp.zeros((16, 8), jnp.float32)
+    with pytest.raises(ValueError, match=rf'metric="ip".*{kernel}'):
+        search.beam_search_single(
+            db, jnp.zeros((16, 4), jnp.int32), db[0], jnp.zeros((1,),
+                                                                jnp.int32),
+            beam_width=8, max_hops=4, metric="ip", kernel=kernel)

@@ -201,3 +201,22 @@ def test_nsg_prune_has_no_scatter(one_chip):
         r,
     ).compile()
     assert " scatter(" not in compiled.as_text()
+
+
+def test_batched_search_ip_compiles_at_1m(one_chip, on_tpu):
+    """The served ``ip`` program at N = 1M, d = 200 (B = 1,024, beam 128,
+    instrumented, with ``M²``) fits the chip and scores ``-q·x`` as a
+    float32 multiply and sum: no dot, which would run float32 in bfloat16
+    passes at the default precision."""
+    b, beam, r = 1024, 128, 38
+    params = SearchParams(k=10, beam_width=beam, max_hops=4 * beam,
+                          instrument=True, metric="ip")
+    compiled = _batched_search.lower(
+        *_search_args(one_chip, 1_000_000, 200, b=b, r=r), None, None, None,
+        _sds((), jnp.float32, one_chip), params=params
+    ).compile()
+    text = compiled.as_text()
+    assert not re.search(r" (dot|convolution)\(", text)
+    assert re.search(rf"f32\[{b},{r}\]\S* reduce\(%mul", text)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
